@@ -50,7 +50,9 @@ def bench_kernel_reconstruct():
         (np.random.RandomState(0).rand(spec.n) < 0.5), jnp.float32
     )
     rows = []
-    for impl, key in (("ref", "ref"), ("pallas", "pallas_interpret")):
+    interp = ops._interpret()
+    pallas_key = "pallas_interpret" if interp else "pallas"
+    for impl, key in (("ref", "ref"), ("pallas", pallas_key)):
         f = jax.jit(lambda z_, impl=impl: ops.reconstruct(spec, z_, impl=impl))
         f(z).block_until_ready()
         t0 = time.perf_counter()
@@ -61,7 +63,7 @@ def bench_kernel_reconstruct():
         rows.append({
             "bench": "kernel_qz_reconstruct", "impl": key, "us": us,
             "m": spec.m, "n": spec.n, "d": spec.d,
-            "regression_comparable": impl == "ref",
+            "regression_comparable": impl == "ref" or not interp,
         })
         _emit(f"kernel_qz_reconstruct_{key}", us,
               f"m={spec.m};n={spec.n};d={spec.d}")
@@ -1007,7 +1009,8 @@ def bench_serve(full=False):
     (the jnp fallback) and the one interpret-mode Pallas row is keyed
     ``impl='u8_pallas_interpret'`` with ``regression_comparable:
     False`` (interpreter artifact, not kernel perf — same convention
-    as kernel_qz_reconstruct).  The dense row serves the SAME sampled
+    as kernel_qz_reconstruct; on a TPU it is the compiled
+    ``u8_pallas`` row).  The dense row serves the SAME sampled
     weights through model.decode_step — the no-zampling baseline.
 
     ``serve_delta`` rows: exact delta-vs-full broadcast bytes on a
@@ -1026,6 +1029,7 @@ def bench_serve(full=False):
 
     from repro.configs.registry import get_arch
     from repro.core import ZamplingConfig, build_specs, init_state
+    from repro.kernels import ops
     from repro.core.zampling import sample_weights
     from repro.models import build_model
     from repro.serve import (apply_delta, build_serve_engine, delta_report,
@@ -1123,9 +1127,11 @@ def bench_serve(full=False):
                   f";zampled_bytes={zamp_bytes[mode]}")
 
         if cfg is small:
-            # one interpret-mode Pallas step: correctness-path timing
-            # only (the interpreter walks the one-hot contraction), so
-            # the row is excluded from perf regression comparisons
+            # one Pallas step; on the CPU it runs in the interpreter
+            # (correctness-path timing only: the interpreter walks the
+            # one-hot contraction), so that row is excluded from perf
+            # regression comparisons
+            interp = ops._interpret()
             engine = build_serve_engine(model, sstate, mode="streaming",
                                         impl="pallas")
             arrays = engine.arrays_of(sstate)
@@ -1136,16 +1142,17 @@ def bench_serve(full=False):
             rows.append({
                 "bench": "serve_decode", "K": cfg.d_model,
                 "strategy": "streaming",
-                "impl": "u8_pallas_interpret",
+                "impl": "u8_pallas_interpret" if interp else "u8_pallas",
                 "tok_s": B / dt, "us": dt / B * 1e6,
                 "resident_zampled_bytes": zamp_bytes["streaming"],
                 "dense_bytes": sstate.dense_bytes(),
                 "m_total": zspecs.m_total, "n_total": zspecs.n_total,
                 "bit_exact_vs_load": True,
-                "regression_comparable": False,
+                "regression_comparable": not interp,
             })
             _emit(f"serve_decode_streaming_pallas_d{cfg.d_model}",
-                  dt / B * 1e6, "interpret-mode;not-comparable")
+                  dt / B * 1e6,
+                  "interpret-mode;not-comparable" if interp else "compiled")
 
     # --- delta broadcast on a converged round ----------------------------
     model = build_model(small)
@@ -1384,6 +1391,9 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     failed = []

@@ -50,6 +50,9 @@ from repro.data import (
 from repro.fault import ClientPopulation, FaultPlan
 from repro.models.mlp import SMALL_DIMS, init_mlp_params, mlp_accuracy, mlp_loss
 from repro.train import evaluate, federated_fit
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--rounds", type=int, default=25)

@@ -10,10 +10,13 @@ that sampled networks match the expected network's accuracy.
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ZamplingConfig, build_specs, init_state
 from repro.data import make_teacher_dataset
 from repro.models.mlp import SMALL_DIMS, init_mlp_params, mlp_accuracy, mlp_loss
 from repro.train import LocalTrainConfig, evaluate, train_local_zampling
+
+enable_compile_cache()
 
 ds = make_teacher_dataset(n_train=6000, n_test=1200, seed=0)
 test_batch = {"x": jnp.asarray(ds.x_test), "y": jnp.asarray(ds.y_test)}

@@ -50,6 +50,9 @@ from repro.serve import (
     serve_from_compressed,
 )
 from repro.models import build_model
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--mode", choices=["load", "streaming", "cached"],

@@ -19,8 +19,8 @@ subsystems:
  - ``metering``  — exact uplink AND downlink byte accounting per round
    per (transport, codec) (surfaced in round metrics, paper tables,
    benchmarks);
- - ``shardmap``  — the jax-version compat shim for entering
-   ``shard_map`` from an ambient mesh (shared with ``kernels``).
+ - ``shardmap``  — entering ``shard_map`` over the context mesh
+   (``jax.set_mesh``) and sizing mesh axes (shared with ``kernels``).
 """
 
 from .bitpack import pack_mask, packed_len, packed_popcount_sum, unpack_mask
@@ -47,7 +47,7 @@ from .protocol import (
     resolve_transport,
     transport_names,
 )
-from .shardmap import axis_size, shard_map_compat
+from .shardmap import axis_size, shard_map
 
 __all__ = [
     "pack_mask", "packed_len", "packed_popcount_sum", "unpack_mask",
@@ -58,5 +58,5 @@ __all__ = [
     "wire_table", "downlink_table",
     "Transport", "get_transport", "register_transport",
     "resolve_transport", "transport_names",
-    "axis_size", "shard_map_compat",
+    "axis_size", "shard_map",
 ]

@@ -1,11 +1,12 @@
-"""jax-version compat for entering ``shard_map`` and sizing mesh axes.
+"""Entering ``shard_map`` and sizing mesh axes.
 
 The transport collectives (``protocol``), the sharded reconstruction
 (``kernels.qz_sharded``) and the scan-over-rounds sharded driver
-(``train.fit``) all run bodies under ``shard_map``.  On jax versions
-without the top-level ``jax.shard_map`` entry point the mesh is taken
-from the ambient ``with mesh:`` context instead, so every path is
-exercisable on a forced-multi-device CPU too.
+(``train.fit``) all run bodies under ``shard_map``.  The map takes its
+mesh from the context, so callers enter ``jax.set_mesh(mesh)``; a map
+nested in another map's body (the sharded reconstruction inside a
+sharded federated round) composes with the partially-manual context
+mesh.
 """
 
 from __future__ import annotations
@@ -16,31 +17,12 @@ import jax
 
 
 def axis_size(axis_names: Sequence[str]) -> int:
-    """Total device count across the named mesh axes, inside shard_map.
-
-    ``psum`` of a python scalar constant-folds to a concrete int at
-    trace time on every jax version (``jax.lax.axis_size`` does not
-    exist on 0.4.x).
-    """
-    return jax.lax.psum(1, tuple(axis_names))
+    """Total device count across the named mesh axes, inside shard_map."""
+    return jax.lax.axis_size(tuple(axis_names))
 
 
-def shard_map_compat(f, axis_names: Sequence[str], in_specs, out_specs):
-    """``jax.shard_map`` when available; else the experimental API bound
-    to the ambient ``with mesh:`` context (jax<=0.4.x spelling)."""
-    names = tuple(axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             axis_names=set(names), check_vma=False)
-    from jax._src import mesh as mesh_lib
-    from jax.experimental.shard_map import shard_map as _sm
-
-    mesh = mesh_lib.thread_resources.env.physical_mesh
-    missing = [a for a in names if mesh.empty or a not in mesh.axis_names]
-    if missing:
-        raise RuntimeError(
-            f"shard_map needs an active mesh with axes {names} "
-            f"(enter `with mesh:`) on this jax version; missing {missing}"
-        )
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+def shard_map(f, axis_names: Sequence[str], in_specs, out_specs):
+    """``jax.shard_map`` manual over ``axis_names`` of the context mesh
+    (entered with ``jax.set_mesh``)."""
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(axis_names), check_vma=False)

@@ -86,8 +86,12 @@ def hash_u32(*words):
 
 
 def u32_to_uniform(u):
-    """u32 -> float32 uniform in (0, 1] (never 0: safe for log)."""
-    return (u >> np.uint32(8)).astype(jnp.float32) * _INV_2_24 + _INV_2_24
+    """u32 -> float32 uniform in (0, 1] (never 0: safe for log).
+
+    The 24-bit value goes through int32, which is exact and is the cast
+    the TPU kernel compiler accepts (it has no uint32 -> float32)."""
+    u24 = (u >> np.uint32(8)).astype(jnp.int32)
+    return u24.astype(jnp.float32) * _INV_2_24 + _INV_2_24
 
 
 def gaussian_from_u32(u_a, u_b):

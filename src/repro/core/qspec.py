@@ -169,12 +169,10 @@ def padded_row_valid(spec: QSpec, rp):
     return (rp % spec.m_pad_loc) < spec.m_blk
 
 
-def row_indices(spec: QSpec, rows):
-    """In-window column indices for the given (global) row ids.
-
-    Returns int32 ``(..., d)`` in ``[0, window)``; the global z index is
-    ``(rows // rows_per_window) * window + idx``.
-    """
+def row_hashes(spec: QSpec, rows):
+    """(base, stride) uint32 words of the given row ids, shaped like
+    ``rows``: edge k of a row sits at in-window column
+    ``(base + k·stride) mod window`` (``edge_index``)."""
     rows = jnp.asarray(rows).astype(jnp.uint32)
     base = hash_u32(spec.seed, spec.tensor_id, rows, _CTR_BASE) & np.uint32(
         spec.window - 1
@@ -184,20 +182,52 @@ def row_indices(spec: QSpec, rows):
         hash_u32(spec.seed, spec.tensor_id, rows, _CTR_STRIDE)
         % np.uint32(spec.window // 2)
     ) * np.uint32(2) + np.uint32(1)
+    return base, stride
+
+
+def edge_index(spec: QSpec, base, stride, k):
+    """In-window column of edge ``k`` (int32, shaped like base/k)."""
+    return ((base + stride * k) & np.uint32(spec.window - 1)).astype(
+        jnp.int32)
+
+
+def edge_value(spec: QSpec, rows, k, dtype=jnp.float32):
+    """Gaussian coefficient of edge ``k`` of each row, shaped like
+    ``rows`` broadcast against ``k`` (a static int or a uint32 array)."""
+    rows = jnp.asarray(rows).astype(jnp.uint32)
+    ua = hash_u32(spec.seed, spec.tensor_id, rows, _CTR_VAL + 2 * k)
+    ub = hash_u32(spec.seed, spec.tensor_id, rows, _CTR_VAL + 2 * k + 1)
+    g = gaussian_from_u32(ua, ub) * np.float32(spec.sigma)
+    return g.astype(dtype)
+
+
+def edge_sum(terms):
+    """Sum the trailing edge-slot axis in ascending slot order.
+
+    THE reduction order of ``w = Σ_k q_k z_idx_k`` for every path (ref,
+    chunked, sharded, serve, and the Pallas kernels, which accumulate
+    slot by slot): a fused XLA reduce picks its own, context-dependent
+    order, so bit-identical paths spell the order out.
+    """
+    acc = terms[..., 0]
+    for k in range(1, terms.shape[-1]):
+        acc = acc + terms[..., k]
+    return acc
+
+
+def row_indices(spec: QSpec, rows):
+    """In-window column indices for the given (global) row ids.
+
+    Returns int32 ``(..., d)`` in ``[0, window)``; the global z index is
+    ``(rows // rows_per_window) * window + idx``.
+    """
+    base, stride = row_hashes(spec, rows)
     k = jnp.arange(spec.d, dtype=jnp.uint32)
-    idx = (base[..., None] + stride[..., None] * k) & np.uint32(spec.window - 1)
-    return idx.astype(jnp.int32)
+    return edge_index(spec, base[..., None], stride[..., None], k)
 
 
 def row_values(spec: QSpec, rows, dtype=jnp.float32):
     """Gaussian coefficients ``q_{i,k} ~ N(0, 6/(d·fan_in))``, shape (..., d)."""
     rows = jnp.asarray(rows).astype(jnp.uint32)
     k = jnp.arange(spec.d, dtype=jnp.uint32)
-    ua = hash_u32(
-        spec.seed, spec.tensor_id, rows[..., None], _CTR_VAL + 2 * k
-    )
-    ub = hash_u32(
-        spec.seed, spec.tensor_id, rows[..., None], _CTR_VAL + 2 * k + 1
-    )
-    g = gaussian_from_u32(ua, ub) * np.float32(spec.sigma)
-    return g.astype(dtype)
+    return edge_value(spec, rows[..., None], k, dtype)

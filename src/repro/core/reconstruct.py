@@ -89,7 +89,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .qspec import QSpec, padded_row_valid, padded_row_window, row_indices, row_values
+from .qspec import (
+    QSpec,
+    edge_sum,
+    padded_row_valid,
+    padded_row_window,
+    row_indices,
+    row_values,
+)
 from .transpose_plan import build_transpose_plan, resolve_bwd_path, row_plan
 
 
@@ -157,7 +164,7 @@ def _w_padded(spec: QSpec, z):
     """All padded rows: w_pad (m_pad,) f32."""
     gidx, vals = _row_plan(spec)
     zg = _gather_rows(z.astype(jnp.float32), gidx.reshape(-1, 1))
-    return jnp.sum(vals * zg.reshape(spec.m_pad, spec.d), axis=-1)
+    return edge_sum(vals * zg.reshape(spec.m_pad, spec.d))
 
 
 def _select_valid(spec: QSpec, w_pad):
@@ -237,17 +244,15 @@ def reconstruct_batched_ref(spec: QSpec, Z, dtype=None, row_sharding=None):
     if spec.m_pad * spec.d >= _batch_map_threshold():
         flat = gidx.reshape(-1, 1)
         w_pad = jax.lax.map(
-            lambda z: jnp.sum(
-                vals * _gather_rows(z, flat).reshape(spec.m_pad, spec.d),
-                axis=-1,
-            ),
+            lambda z: edge_sum(
+                vals * _gather_rows(z, flat).reshape(spec.m_pad, spec.d)),
             zf,
         )
     else:
         zg = _gather_cols(zf, gidx.reshape(-1, 1)).reshape(
             Z.shape[0], spec.m_pad, spec.d
         )
-        w_pad = jnp.einsum("md,kmd->km", vals, zg)
+        w_pad = edge_sum(vals * zg)
     w = _select_valid_batched(spec, w_pad)
     return _unmove_batched(spec, w).astype(dtype)
 

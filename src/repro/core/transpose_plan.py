@@ -66,6 +66,7 @@ the bit-exactness oracle.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from dataclasses import dataclass
@@ -135,6 +136,17 @@ def resolve_bwd_path(path: str | None = None):
 # Cached forward row plan (spec-static)
 # ---------------------------------------------------------------------------
 
+@contextlib.contextmanager
+def _host_eval():
+    """Concrete, eager evaluation of a host-side plan build.  The first
+    build may happen inside a trace (jit/vmap/grad of a consumer, or a
+    shard_map body, whose manual context mesh an eager op must not
+    inherit)."""
+    with jax.ensure_compile_time_eval(), jax.sharding.use_abstract_mesh(
+            jax.sharding.AbstractMesh((), ())):
+        yield
+
+
 # Bounded like ops._vmap_cores: eviction costs a one-time rebuild,
 # never correctness.  Entries are O(m_pad·d) numpy, so keep it small.
 @functools.lru_cache(maxsize=32)
@@ -146,9 +158,7 @@ def row_plan(spec: QSpec):
     ops, evaluated eagerly and frozen).
     """
     rp = np.arange(spec.m_pad, dtype=np.uint32)
-    # the first build may happen inside a trace (jit/vmap/grad of a
-    # consumer): force eager evaluation so the result is concrete numpy
-    with jax.ensure_compile_time_eval():
+    with _host_eval():
         win = np.asarray(padded_row_window(spec, rp.astype(np.int32)))
         idx = np.asarray(row_indices(spec, rp))
         vals = np.asarray(row_values(spec, rp, dtype=jnp.float32))
@@ -207,7 +217,7 @@ def _edges(spec: QSpec, order: str):
         raise ValueError(f"unknown plan order {order!r}; valid: {_ORDERS}")
     gidx, vals = row_plan(spec)
     rp = np.arange(spec.m_pad, dtype=np.int64)
-    with jax.ensure_compile_time_eval():
+    with _host_eval():
         valid = np.asarray(padded_row_valid(spec, rp))
     r_local = (rp % spec.rows_per_window).astype(np.int64)
     coord = gidx.astype(np.int64)  # (m_pad, d) global z coordinate

@@ -4,9 +4,10 @@
 training/serving step turns the sampled mask ``z`` back into weights.
 Dispatch:
 
- - impl='ref'     pure-jnp oracle (default on CPU)
- - impl='pallas'  the Pallas TPU kernel (interpret=True on CPU;
-                  single-block layout, shard_count == 1)
+ - impl='ref'     pure-jnp oracle (the default on every backend)
+ - impl='pallas'  the Pallas TPU kernels, compiled on a TPU and run by
+                  the Pallas interpreter on the CPU (``_interpret``);
+                  single-block layout, shard_count == 1
  - distributed    when the spec carries shard_count > 1 and a mesh is
                   active, the manually-partitioned shard_map op emits
                   the tensor directly in consumer sharding
@@ -80,7 +81,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.qspec import QSpec, padded_row_window, row_indices, row_values
+from ..core.qspec import (
+    QSpec,
+    edge_sum,
+    padded_row_window,
+    row_indices,
+    row_values,
+)
 from ..core.hashrng import bernoulli_u32
 from ..core.sampling import (
     mask_u32,
@@ -111,6 +118,12 @@ from . import qz_reconstruct as _pk
 
 _DEFAULT_IMPL = "ref"
 _VALID_IMPLS = ("ref", "pallas")
+
+
+def _interpret() -> bool:
+    """Run the Pallas kernels in the interpreter only on the CPU
+    backend, which has no kernel compiler; on a TPU they compile."""
+    return jax.default_backend() == "cpu"
 
 
 def set_default_impl(impl: str) -> None:
@@ -163,7 +176,7 @@ def _ref_chunked(spec: QSpec, z, chunks: int):
 
     def one(c):
         gidx, vals = _chunk_rows_global(spec, c, rpc)
-        return jnp.sum(vals * jnp.take(zf, gidx, axis=0), axis=-1)
+        return edge_sum(vals * jnp.take(zf, gidx, axis=0))
 
     w_pad = jax.lax.map(one, jnp.arange(chunks)).reshape(-1)[: spec.m_pad]
     return _unmove(spec, _select_valid(spec, w_pad))
@@ -179,7 +192,7 @@ def _ref_chunked_batched(spec: QSpec, Z, chunks: int):
     def one(c):
         gidx, vals = _chunk_rows_global(spec, c, rpc)
         return jax.lax.map(
-            lambda z: jnp.sum(vals * jnp.take(z, gidx, axis=0), axis=-1), zf
+            lambda z: edge_sum(vals * jnp.take(z, gidx, axis=0)), zf
         )  # (K, rpc)
 
     w_pad = jax.lax.map(one, jnp.arange(chunks))  # (chunks, K, rpc)
@@ -321,7 +334,8 @@ def _fwd_one(spec: QSpec, z, impl, chunks, model_size):
     if impl == "pallas":
         assert spec.shard_count == 1, "pallas path is single-block layout"
         # kernel emits rows in moved (sharding-major) flat order
-        return _unmove(spec, _pk.qz_reconstruct_fwd(spec, z))
+        return _unmove(spec, _pk.qz_reconstruct_fwd(
+            spec, z, interpret=_interpret()))
     if chunks > 1:
         return _ref_chunked(spec, z, chunks)
     return reconstruct_ref(spec, z, dtype=jnp.float32)
@@ -341,8 +355,10 @@ def _bwd_one(spec: QSpec, g, impl, chunks, model_size):
     if impl == "pallas":
         if kind == "plan":
             return _pk.qz_reconstruct_bwd_plan(spec, _move(spec, g),
-                                               order=order)
-        return _pk.qz_reconstruct_bwd(spec, _move(spec, g))
+                                               order=order,
+                                               interpret=_interpret())
+        return _pk.qz_reconstruct_bwd(spec, _move(spec, g),
+                                      interpret=_interpret())
     if chunks > 1:
         if kind == "plan":
             return _grad_chunked_plan(spec, g, chunks, order)
@@ -358,7 +374,8 @@ def _fwd_many(spec: QSpec, Z, impl, chunks, model_size):
     if impl == "pallas":
         assert spec.shard_count == 1, "pallas path is single-block layout"
         # kernel emits rows in moved (sharding-major) flat order
-        return _unmove_batched(spec, _pk.qz_reconstruct_batched_fwd(spec, Z))
+        return _unmove_batched(spec, _pk.qz_reconstruct_batched_fwd(
+            spec, Z, interpret=_interpret()))
     if chunks > 1:
         return _ref_chunked_batched(spec, Z, chunks)
     return reconstruct_batched_ref(spec, Z, dtype=jnp.float32)
@@ -374,9 +391,10 @@ def _bwd_many(spec: QSpec, G, impl, chunks, model_size):
     if impl == "pallas":
         if kind == "plan":
             return _pk.qz_reconstruct_batched_bwd_plan(
-                spec, _move_batched(spec, G), order=order
-            )
-        return _pk.qz_reconstruct_batched_bwd(spec, _move_batched(spec, G))
+                spec, _move_batched(spec, G), order=order,
+                interpret=_interpret())
+        return _pk.qz_reconstruct_batched_bwd(spec, _move_batched(spec, G),
+                                              interpret=_interpret())
     if chunks > 1:
         if kind == "plan":
             return _grad_chunked_batched_plan(spec, G, chunks, order)
@@ -547,7 +565,8 @@ def _fwd_one_fused(spec: QSpec, p, step, impl, chunks, model_size,
     elif impl == "pallas" and (not qpacked or _packed_fusable(spec, qbits)):
         assert spec.shard_count == 1, "pallas path is single-block layout"
         return _unmove(spec, _pk.qz_sample_reconstruct_fwd(
-            spec, p, step, qbits=qbits, qpacked=qpacked))
+            spec, p, step, qbits=qbits, qpacked=qpacked,
+            interpret=_interpret()))
     z = _sample_one(spec, p, step, qbits, qpacked)
     if chunks > 1:
         return _ref_chunked(spec, z, chunks)
@@ -567,7 +586,8 @@ def _fwd_many_fused(spec: QSpec, P, steps, impl, chunks, model_size,
         assert spec.shard_count == 1, "pallas path is single-block layout"
         return _unmove_batched(
             spec, _pk.qz_sample_reconstruct_batched_fwd(
-                spec, P, steps, qbits=qbits, qpacked=qpacked)
+                spec, P, steps, qbits=qbits, qpacked=qpacked,
+                interpret=_interpret())
         )
     Z = _sample_one(spec, P, steps, qbits, qpacked)
     if chunks > 1:
@@ -754,7 +774,8 @@ def sample_reconstruct_batched(spec: QSpec, P, steps, *, dtype=jnp.float32,
 
 def _pack_one(spec: QSpec, p, step, impl):
     if impl == "pallas" and spec.window % 32 == 0:
-        return _pk.qz_sample_pack_fwd(spec, p, step)
+        return _pk.qz_sample_pack_fwd(spec, p, step,
+                                      interpret=_interpret())
     from ..comm.bitpack import pack_mask
 
     return pack_mask(_sample_one(spec, p, step))
@@ -762,7 +783,8 @@ def _pack_one(spec: QSpec, p, step, impl):
 
 def _pack_many(spec: QSpec, P, steps, impl):
     if impl == "pallas" and spec.window % 32 == 0:
-        return _pk.qz_sample_pack_batched_fwd(spec, P, steps)
+        return _pk.qz_sample_pack_batched_fwd(spec, P, steps,
+                                              interpret=_interpret())
     from ..comm.bitpack import pack_mask
 
     return pack_mask(_sample_one(spec, P, steps))
@@ -833,7 +855,10 @@ def sample_pack_batched(spec: QSpec, P, steps, *,
 # tree: per (window, bm)-block in ascending grid order, the block's
 # rows scatter into an i-aligned (NI, d_out) weight tile (each cell a
 # single term, NI = bm//d_out + 2 static), and the accumulator takes
-# ``y += dot(x[i_lo:i_lo+NI], tile)``.  Identical dot shapes, operand
+# ``y += dot(x[i_lo:i_lo+NI], tile)`` (a matvec is the one-row
+# matmul).  Each weight value is itself summed over its d edge slots in
+# ascending order (``core.qspec.edge_sum``) — a fused reduce's order
+# depends on its context too.  Identical dot shapes, operand
 # values, and add order at every step ⇒ identical bits by
 # construction (up to IEEE signed zeros in all-dead tile cells),
 # whatever the backend's dot does inside one tile.
@@ -939,7 +964,7 @@ def _serve_edge_weights(spec: QSpec, p, step, rows, qbits, qpacked=False):
     else:
         thr = quant_threshold_u24(pw, qbits)
         bits = ((u >> np.uint32(8)) < thr).astype(jnp.float32)
-    return jnp.sum(vals * bits, axis=-1)
+    return edge_sum(vals * bits)
 
 
 def serve_tile_rows(bm: int, d_out: int) -> int:
@@ -982,8 +1007,9 @@ def _serve_contract_blocks(spec: QSpec, x, row_offset, d_in, d_out, bm,
     w0, nblk, bpw = serve_block_grid(spec, bm, row_offset, sub)
     rpw = spec.rows_per_window
     xf = x.astype(jnp.float32)
-    pad = ((0, 0), (0, ni)) if xf.ndim == 2 else ((0, ni),)
-    xpad = jnp.pad(xf, pad)
+    if x.ndim == 1:  # a matvec is contracted as the one-row matmul
+        xf = xf[None]
+    xpad = jnp.pad(xf, ((0, 0), (0, ni)))
     lane = jnp.arange(bm, dtype=jnp.int32)
 
     def body(y, t):
@@ -998,17 +1024,13 @@ def _serve_contract_blocks(spec: QSpec, x, row_offset, d_in, d_out, bm,
                         ni * d_out)
         tile = jnp.zeros((ni * d_out,), jnp.float32)
         tile = tile.at[pos].add(w_blk, mode="drop").reshape(ni, d_out)
-        if xf.ndim == 2:
-            xseg = jax.lax.dynamic_slice(xpad, (0, i_lo),
-                                         (xpad.shape[0], ni))
-        else:
-            xseg = jax.lax.dynamic_slice(xpad, (i_lo,), (ni,))
+        xseg = jax.lax.dynamic_slice(xpad, (0, i_lo), (xpad.shape[0], ni))
         return (y + jnp.dot(xseg, tile,
                             preferred_element_type=jnp.float32), None)
 
-    y0 = jnp.zeros(xf.shape[:-1] + (d_out,), jnp.float32)
+    y0 = jnp.zeros((xf.shape[0], d_out), jnp.float32)
     y, _ = jax.lax.scan(body, y0, jnp.arange(nblk, dtype=jnp.int32))
-    return y
+    return y[0] if x.ndim == 1 else y
 
 
 def _serve_contract_chunked(spec: QSpec, p, step, x, row_offset, d_in,
@@ -1104,7 +1126,8 @@ def _serve_contract(spec, words, step, x, group, qbits, impl, bm,
 
         fn = qz_sample_matvec if x.ndim == 1 else qz_sample_matmul
         return fn(spec, p, step, x, row_offset=row_offset, d_in=d_in,
-                  d_out=d_out, qbits=qbits, qpacked=qpacked, bm=bm)
+                  d_out=d_out, qbits=qbits, qpacked=qpacked, bm=bm,
+                  interpret=_interpret())
     return _serve_contract_chunked(spec, p, step, x, row_offset, d_in,
                                    d_out, qbits, bm, qpacked)
 

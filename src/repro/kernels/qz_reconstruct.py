@@ -1,6 +1,6 @@
-"""Pallas TPU kernel: materialization-free ``w = Q z`` reconstruction.
+"""Pallas TPU kernels: materialization-free ``w = Q z`` reconstruction.
 
-TPU-native design (DESIGN.md §3):
+Design (DESIGN.md §3):
 
  - grid = (num_windows, blocks_per_window); block (i, j) produces ``bm``
    weights whose Q-rows all read from z-window ``i`` — the (window,)
@@ -8,113 +8,79 @@ TPU-native design (DESIGN.md §3):
  - indices/values are *regenerated* inside the kernel from the hash RNG
    (no Q operand at all), so HBM traffic is O(n + m) instead of
    O(m·d) for a materialized sparse Q.
- - the in-window gather ``z[idx]`` is expressed as a one-hot matmul
-   ``onehot(idx) @ z_win`` — a (bm·d, window) × (window,) contraction
-   that maps onto the MXU instead of relying on VPU dynamic gather
-   support.  bm=256, window=512, d=8 ⇒ 4 MiB of one-hot bf16 in VMEM.
+ - every kernel is K-client batched: the client axis rides on the
+   sublanes of each block (``(K, window)`` z-slab in, ``(K, bm)``
+   weight tile out, rows on the lanes), so Q's hash-RNG edges are
+   regenerated once per block instead of K times.  The single-client
+   entry points are the K=1 case of the same kernels.
+ - the in-window gather ``z[idx]`` is one MXU contraction per edge
+   slot: for each of the ``d`` (static) edge slots, the (window, bm)
+   one-hot of that slot's column against the lane-aligned window,
+   ``zslab (K, window) @ onehot (window, bm)``, scaled by the slot's
+   values and accumulated in ascending slot order.  Every array stays
+   2-D and lane-aligned: no reshape ever crosses lanes.
 
-The backward ``grad_z = Q^T grad_w`` has two kernels, gated like the
-ref path by ``core.transpose_plan.resolve_bwd_path()`` (env
-``REPRO_BWD_PLAN``; ``kernels.ops`` dispatches):
+Backward ``grad_z = Q^T grad_w`` (``kernels.ops`` dispatches via
+``core.transpose_plan.resolve_bwd_path()``):
 
- - PLAN (default, ``qz_reconstruct_bwd_plan``): the cached per-spec
-   transpose plan re-binned to this grid (``build_block_plan``): cell
-   (window i, row-block j, coordinate c) carries the degree-padded
-   incoming edges whose source row lies in rows [j·bm, (j+1)·bm) of
-   window i, rows stored BLOCK-relative.  The (window·deg) gather of
-   grad_w maps onto the same one-hot MXU contraction as the forward —
-   ``onehot(src_rows) (window·deg, bm) @ g (bm,)`` — followed by a
-   vals-multiply and deg-axis reduction; the plan slab (rows + vals,
-   the only extra operands) rides in with its own BlockSpec.  Edge
-   order inside a cell follows the plan's ordering contract
-   ('canonical' = by source row), but blocks still accumulate over the
+ - PLAN (default, ``qz_reconstruct_batched_bwd_plan``): the cached
+   per-spec transpose plan re-binned to this grid (``build_block_plan``)
+   — cell (window i, row-block j, coordinate c) carries the
+   degree-padded incoming edges whose source row lies in rows
+   [j·bm, (j+1)·bm) of window i, rows stored BLOCK-relative.  For each
+   of the ``deg`` plan slots the gather is ``g (K, bm) @ onehot (bm,
+   window)``, scaled by the slot's values.  Blocks accumulate over the
    ``j`` grid dimension, so the Pallas plan path is its OWN ordering
-   mode: deterministic and exactly reproducible per (spec, bm), and
-   ``allclose`` vs the ref plan / scatter paths.
- - SCATTER (oracle, ``qz_reconstruct_bwd``): the transposed one-hot
-   contraction ``contrib (bm·d,) @ onehot (bm·d, window)``.
+   mode: deterministic per (spec, bm), ``allclose`` vs the ref plan /
+   scatter paths.
+ - SCATTER (oracle, ``qz_reconstruct_batched_bwd``): per edge slot,
+   ``(g · vals) (K, bm) @ onehot (bm, window)``.
 
 Both accumulate over the ``j`` (inner) grid dimension into the same
-z-window output block (revisited-output pattern).
-
-Batched multi-client kernels (``qz_reconstruct_batched_fwd/bwd``):
-the federated round simulates K clients per host, each reconstructing
-from its own mask ``z^(k)``.  The batched grid is IDENTICAL to the
-single-client grid ``(num_windows, blocks_per_window)`` — the client
-axis is carried inside the block, never in the grid, so the hash-RNG
-indices/values of Q are regenerated once per block instead of K times:
-
- - input is the transposed z-slab ``Zt (n, K)``; block (i, j) reads the
-   ``(window, K)`` slab of window ``i`` — K client columns ride along
-   for free in the same DMA;
- - the gather-as-matmul becomes ``onehot (bm·d, window) @ slab
-   (window, K)`` so the MXU produces K output columns per pass (the
-   single-client kernel wastes 127/128 MXU lanes on a (window,) vector;
-   with K clients the same one-hot feeds K lanes);
- - output tile is ``(bm, K)``; the wrapper transposes back to (K, m).
-
-VMEM budget per block at bm=256, window=512, d=8, K=32 (f32):
-slab 512·32·4 = 64 KiB, one-hot 256·8·512·4 = 4 MiB, zsel
-256·8·32·4 = 256 KiB, out 256·32·4 = 32 KiB — ~4.4 MiB total, well
-under the ~16 MiB/core VMEM budget; K up to ~128 fits (one-hot
-dominates and is K-independent).  The backward accumulates the
-transposed contraction into a ``(window, K)`` grad-z-slab with the
-same revisited-output pattern as the single-client kernel.
+``(K, window)`` grad-z block (revisited-output pattern).  The gathers
+that carry f32 values (the composed forward's z, the backward's
+cotangents) run at ``Precision.HIGHEST``, so the one-hot selection is
+exact on the MXU; the fused draws feed {0,1} masks, exact at any
+precision.
 
 Fused mask lifecycle (``qz_sample_reconstruct_*`` /
-``qz_sample_pack_*``): the paper's mask ``z ~ Bern(f(s))`` is n BITS,
-yet the composed pipeline materializes it as an f32 array in HBM three
-times per round — the sampling output, the reconstruction input, and
-the pre-bitpack upload draw.  The fused kernels take the *probability*
-vector ``p = f(s)`` (or the transposed ``(n, K)`` p-slab) and draw
+``qz_sample_pack_*``): the paper's mask ``z ~ Bern(f(s))`` is n BITS.
+The fused kernels take the *probability* slab ``p = f(s)`` and draw
 ``z`` in-block from the counter-based hash RNG
 (``core.sampling.mask_u32``: words ``(seed, tensor_id, MASK_CTR, step,
-coord)``), so the mask only ever exists as a ``(window,)`` /
-``(window, K)`` VMEM value between the p-window DMA and the one-hot
-contraction:
+coord)``), so the mask only ever exists as a ``(K, window)`` VMEM value
+between the p-window DMA and the one-hot contraction:
 
- - ``qz_sample_reconstruct_fwd`` (+``_batched``): p in, ``w = Q
-   Bern(p)`` out.  Identical grid/one-hot layout to the composed
-   kernels; the only extra operand is the (1,) / (K,) uint32 ``step``
-   draw-counter word, and the only extra in-block work is
-   window-sized hashing (VPU) overlapping the MXU contraction.  The
-   straight-through backward is UNCHANGED (``grad_p = Q^T grad_w``):
-   ``ops.sample_reconstruct`` reuses the composed backward kernels, so
-   fused and composed gradients are bit-identical by construction.
- - ``qz_sample_pack_fwd`` (+``_batched``): the end-of-round upload
-   draw.  p in, ``uint32`` wire lanes out (bit j of lane i is
-   coordinate 32i+j, exactly ``comm.bitpack.pack_mask``); one grid
-   step per z-window emits ``window/32`` lanes (requires
-   ``window % 32 == 0``; smaller windows fall back to the jnp oracle
-   in ``ops``).
+ - ``qz_sample_reconstruct_*``: p in, ``w = Q Bern(p)`` out.  The only
+   extra operand is the ``(K, 1)`` uint32 ``step`` draw-word column.
+   The straight-through backward is UNCHANGED (``grad_p = Q^T
+   grad_w``): ``ops.sample_reconstruct`` reuses the composed backward
+   kernels, so fused and composed gradients are bit-identical by
+   construction.
+ - ``qz_sample_pack_*``: the end-of-round upload draw.  p in, ``uint32``
+   wire lanes out (bit j of lane i is coordinate 32i+j, exactly
+   ``comm.bitpack.pack_mask``); one grid step per z-window emits
+   ``window/32`` lanes per client (requires ``window % 32 == 0``;
+   smaller windows fall back to the jnp oracle in ``ops``).  The lanes
+   are assembled on the MXU as two 16-bit halves (sums of distinct
+   powers of two, exact in f32).
  - QUANTIZED operand (``qbits``, the downlink codec subsystem): the
    fused forward also accepts the server's b-bit broadcast words
-   (``comm.downlink`` ``u8``/``u16``) instead of f32 probabilities —
-   the in-block draw becomes the widened-threshold integer compare
-   ``(hash >> 8) < q<<(24-b) + (q<<(24-b))//(2^b-1)`` (uint32 shifts +
-   one constant divide on the VPU), so the dequantized f32 score
-   vector never exists in HBM or VMEM.  Bit-identical to the f32 draw
-   on the codec's decoded probabilities (tests/test_downlink.py).
-
-VMEM budget for the fused batched forward at bm=256, window=512, d=8,
-K=32 (f32): p-slab 512·32·4 = 64 KiB, in-block z-slab (same shape)
-64 KiB, one-hot 256·8·512·4 = 4 MiB, zsel 256·8·32·4 = 256 KiB, out
-256·32·4 = 32 KiB — ~4.5 MiB, the one-hot still dominating and
-K-independent; K up to ~128 fits in the ~16 MiB/core budget.  Note the
-composed pipeline pays the SAME VMEM for the z-slab but also a
-``(K, n)`` f32 mask round-trip through HBM (4 bytes/coordinate where
-the wire format is 1 bit) plus the straight-through ``p + sg(z - p)``
-elementwise pass; fused, the HBM mask traffic is zero.
+   (``comm.downlink`` ``u8``/``u16``, or with ``qpacked`` the sub-byte
+   codecs' packed uint32 lanes) instead of f32 probabilities — the
+   in-block draw becomes the widened-threshold integer compare
+   ``(hash >> 8) < q<<(24-b) + (q<<(24-b))//(2^b-1)``, so the
+   dequantized f32 score vector never exists in HBM or VMEM.
+   Bit-identical to the f32 draw on the codec's decoded probabilities
+   (tests/test_downlink.py).
 
 Bit-exactness contract (tests/test_fused.py): fused ≡ composed
 (sample → reconstruct → pack) to EXACT equality, forward and gradient,
-on ref and interpret-mode Pallas, single-client, vmap-batched, and the
-shard_map federated path — both sides regenerate the identical mask
-bits from ``(seed, tensor_id, step, coord)``.
-
-Validated in interpret mode against ``ref.reconstruct_ref`` /
-``ref.grad_z_ref`` over shape/dtype sweeps (tests/test_kernels.py) and
-against the batched ref path (tests/test_batched.py).
+on ref and on Pallas (interpret mode on the CPU), single-client,
+vmap-batched, and the shard_map federated path — both sides regenerate
+the identical mask bits from ``(seed, tensor_id, step, coord)``.
+tests/test_tpu_compile.py compiles the main-path kernels for a
+described TPU v5e at the paper's MNIST-FC widths.
 """
 
 from __future__ import annotations
@@ -128,11 +94,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..core.hashrng import bernoulli_u32
-from ..core.qspec import QSpec, row_indices, row_values
+from ..core.qspec import QSpec, edge_index, edge_value, row_hashes
 from ..core.sampling import mask_u32, quant_threshold_u24
 from ..core.transpose_plan import build_block_plan
 
 DEFAULT_BM = 256
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _grid_dims(spec: QSpec, bm: int):
@@ -140,150 +107,67 @@ def _grid_dims(spec: QSpec, bm: int):
     return spec.num_windows, bpw, spec.num_windows * bpw * bm  # m_grid
 
 
-def _block_rows(spec: QSpec, bm: int, *, masked: bool):
-    """Regenerate this grid block's Q rows from the hash RNG.
-
-    Returns (idx (bm, d) in-window, vals (bm, d) f32).  With
-    ``masked`` (backward kernels), padding rows get zeroed vals so they
-    never scatter garbage into grad_z; forward kernels leave them live
-    (their garbage weights are sliced off by the wrapper) but they
-    still index safely in-window.
-    """
-    i = pl.program_id(0)  # window id
-    j = pl.program_id(1)  # block within window
-    rows = i * spec.rows_per_window + j * bm + jax.lax.iota(jnp.int32, bm)
-    idx = row_indices(spec, rows)  # (bm, d) in [0, window)
-    vals = row_values(spec, rows, dtype=jnp.float32)  # (bm, d)
-    if masked:
-        live = (rows < spec.m) & (
-            jax.lax.iota(jnp.int32, bm) + j * bm < spec.rows_per_window
-        )
-        vals = vals * live[:, None].astype(jnp.float32)
-    return idx, vals
+def _iota(shape, axis: int):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _onehot(idx, window: int):
-    """(bm, d) in-window indices -> (bm*d, window) f32 one-hot — the
-    gather-as-matmul encoding shared by all four kernels."""
-    flat = idx.reshape(-1, 1)
-    return (flat == jax.lax.iota(jnp.int32, window)[None, :]).astype(
-        jnp.float32
-    )
+def _block_rows(spec: QSpec, bm: int, shape):
+    """Global Q-row ids of grid block (i, j) laid out along ``shape``
+    ((1, bm): rows on the lanes; (bm, 1): rows on the sublanes), and
+    their liveness (padding rows past ``m`` or past the window's
+    ``rows_per_window`` are dead)."""
+    local = pl.program_id(1) * bm + _iota(shape, 1 if shape[0] == 1 else 0)
+    rows = pl.program_id(0) * spec.rows_per_window + local
+    return rows, (rows < spec.m) & (local < spec.rows_per_window)
 
 
-def _fwd_kernel(z_ref, w_ref, *, spec: QSpec, bm: int, bpw: int):
-    idx, vals = _block_rows(spec, bm, masked=False)
-    zwin = z_ref[...].astype(jnp.float32)  # (window,)
-    # onehot (bm*d, window) @ zwin (window,)
-    zsel = jnp.dot(_onehot(idx, spec.window), zwin,
-                   preferred_element_type=jnp.float32)
-    w_ref[...] = jnp.sum(vals * zsel.reshape(bm, spec.d), axis=-1)
+def _dot(a, b, precision=None):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=precision)
 
 
-def _bwd_kernel(g_ref, gz_ref, *, spec: QSpec, bm: int, bpw: int):
+def _gather_rows(spec: QSpec, bm: int, zslab, precision):
+    """``(K, bm)`` weights of this block: ``Σ_k vals_k · zslab[:, idx_k]``
+    over the d edge slots, each gather one (window, bm) one-hot MXU
+    contraction, summed in ascending slot order (``qspec.edge_sum``)."""
+    rows, _ = _block_rows(spec, bm, (1, bm))
+    base, stride = row_hashes(spec, rows)
+    coord = _iota((spec.window, bm), 0)
+    acc = None
+    for k in range(spec.d):
+        onehot = (coord == edge_index(spec, base, stride, k)).astype(
+            jnp.float32)
+        term = edge_value(spec, rows, k) * _dot(zslab, onehot, precision)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _bfwd_kernel(z_ref, w_ref, *, spec: QSpec, bm: int):
+    # z may be any f32 (continuous mode): gather at full precision
+    w_ref[...] = _gather_rows(spec, bm, z_ref[...].astype(jnp.float32),
+                              HIGHEST)
+
+
+def _bbwd_kernel(g_ref, gz_ref, *, spec: QSpec, bm: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         gz_ref[...] = jnp.zeros_like(gz_ref)
 
-    idx, vals = _block_rows(spec, bm, masked=True)
-    g = g_ref[...].astype(jnp.float32)  # (bm,)
-    contrib = (vals * g[:, None]).reshape(bm * spec.d)  # (bm*d,)
-    gz_ref[...] += jnp.dot(contrib, _onehot(idx, spec.window),
-                           preferred_element_type=jnp.float32)
+    rows_l, live = _block_rows(spec, bm, (1, bm))
+    rows_s, _ = _block_rows(spec, bm, (bm, 1))
+    base, stride = row_hashes(spec, rows_s)
+    lane = _iota((bm, spec.window), 1)
+    g = jnp.where(live, g_ref[...].astype(jnp.float32), 0.0)  # (K, bm)
+    acc = jnp.zeros(gz_ref.shape, jnp.float32)
+    for k in range(spec.d):
+        onehot = (edge_index(spec, base, stride, k) == lane).astype(
+            jnp.float32)
+        acc = acc + _dot(g * edge_value(spec, rows_l, k), onehot, HIGHEST)
+    gz_ref[...] += acc
 
 
-def qz_reconstruct_fwd(spec: QSpec, z, *, bm: int = DEFAULT_BM,
-                       interpret: bool = True):
-    """Pallas forward: z (n,) f32 -> w (m,) f32 (flat; caller reshapes)."""
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, spec=spec, bm=bm, bpw=bpw),
-        grid=(nw, bpw),
-        in_specs=[pl.BlockSpec((spec.window,), lambda i, j: (i,))],
-        out_specs=pl.BlockSpec((bm,), lambda i, j: (i * bpw + j,)),
-        out_shape=jax.ShapeDtypeStruct((m_grid,), jnp.float32),
-        interpret=interpret,
-    )(z.astype(jnp.float32))
-    # un-pad: rows were laid out per-window with bpw*bm >= rows_per_window
-    if bpw * bm != spec.rows_per_window:
-        out = out.reshape(nw, bpw * bm)[:, : spec.rows_per_window].reshape(-1)
-    return out[: spec.m]
-
-
-def qz_reconstruct_bwd(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,
-                       interpret: bool = True):
-    """Pallas backward: grad_w (m,) -> grad_z (n,) f32."""
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    g = grad_w.reshape(-1).astype(jnp.float32)
-    g = jnp.pad(g, (0, spec.m_pad - spec.m))
-    # re-pad per window to the grid layout
-    if bpw * bm != spec.rows_per_window:
-        g = g.reshape(nw, spec.rows_per_window)
-        g = jnp.pad(g, ((0, 0), (0, bpw * bm - spec.rows_per_window)))
-        g = g.reshape(-1)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, spec=spec, bm=bm, bpw=bpw),
-        grid=(nw, bpw),
-        in_specs=[pl.BlockSpec((bm,), lambda i, j: (i * bpw + j,))],
-        out_specs=pl.BlockSpec((spec.window,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n,), jnp.float32),
-        interpret=interpret,
-    )(g)
-
-
-# ---------------------------------------------------------------------------
-# Batched multi-client kernels (client axis carried in the block)
-# ---------------------------------------------------------------------------
-
-def _bfwd_kernel(zt_ref, w_ref, *, spec: QSpec, bm: int, nclients: int):
-    idx, vals = _block_rows(spec, bm, masked=False)
-    slab = zt_ref[...].astype(jnp.float32)  # (window, K)
-    # one one-hot, K clients: (bm*d, window) @ (window, K) -> (bm*d, K)
-    zsel = jnp.dot(_onehot(idx, spec.window), slab,
-                   preferred_element_type=jnp.float32)
-    w_ref[...] = jnp.sum(
-        vals[..., None] * zsel.reshape(bm, spec.d, nclients), axis=1
-    )
-
-
-def _bbwd_kernel(g_ref, gz_ref, *, spec: QSpec, bm: int, nclients: int):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        gz_ref[...] = jnp.zeros_like(gz_ref)
-
-    idx, vals = _block_rows(spec, bm, masked=True)
-    g = g_ref[...].astype(jnp.float32)  # (bm, K)
-    contrib = (vals[:, :, None] * g[:, None, :]).reshape(
-        bm * spec.d, nclients
-    )
-    gz_ref[...] += jnp.dot(_onehot(idx, spec.window).T, contrib,
-                           preferred_element_type=jnp.float32)
-
-
-def qz_reconstruct_batched_fwd(spec: QSpec, Z, *, bm: int = DEFAULT_BM,
-                               interpret: bool = True):
-    """Batched Pallas forward: Z (K, n) f32 -> W (K, m) f32 (flat)."""
-    nclients = Z.shape[0]
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    zt = Z.astype(jnp.float32).T  # (n, K) — window-major slabs
-    out = pl.pallas_call(
-        functools.partial(_bfwd_kernel, spec=spec, bm=bm, nclients=nclients),
-        grid=(nw, bpw),
-        in_specs=[pl.BlockSpec((spec.window, nclients), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((bm, nclients), lambda i, j: (i * bpw + j, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_grid, nclients), jnp.float32),
-        interpret=interpret,
-    )(zt)
-    if bpw * bm != spec.rows_per_window:
-        out = out.reshape(nw, bpw * bm, nclients)[
-            :, : spec.rows_per_window
-        ].reshape(-1, nclients)
-    return out[: spec.m].T
-
-
-def qz_reconstruct_batched_bwd(spec: QSpec, grad_W, *, bm: int = DEFAULT_BM,
-                               interpret: bool = True):
-    """Batched Pallas backward: grad_W (K, m) -> grad_Z (K, n) f32."""
+def _grid_rows_in(spec: QSpec, grad_W, bm: int):
+    """(K, m) cotangents -> the (K, m_grid) per-window padded layout."""
     nclients = grad_W.shape[0]
     nw, bpw, m_grid = _grid_dims(spec, bm)
     g = grad_W.reshape(nclients, -1).astype(jnp.float32)
@@ -292,16 +176,79 @@ def qz_reconstruct_batched_bwd(spec: QSpec, grad_W, *, bm: int = DEFAULT_BM,
         g = g.reshape(nclients, nw, spec.rows_per_window)
         g = jnp.pad(g, ((0, 0), (0, 0),
                         (0, bpw * bm - spec.rows_per_window)))
-    gt = g.reshape(nclients, m_grid).T  # (m_grid, K)
+    return g.reshape(nclients, m_grid)
+
+
+def _grid_rows_out(spec: QSpec, out, bm: int):
+    """(K, m_grid) kernel rows -> (K, m) (drop the per-window padding)."""
+    nw, bpw, _ = _grid_dims(spec, bm)
+    if bpw * bm != spec.rows_per_window:
+        out = out.reshape(out.shape[0], nw, bpw * bm)[
+            :, :, : spec.rows_per_window
+        ].reshape(out.shape[0], -1)
+    return out[:, : spec.m]
+
+
+def _w_out(spec: QSpec, nclients: int, bm: int):
+    """Out spec + shape of a (K, m_grid) weight-row kernel."""
+    _, bpw, m_grid = _grid_dims(spec, bm)
+    return (pl.BlockSpec((nclients, bm), lambda i, j: (0, i * bpw + j)),
+            jax.ShapeDtypeStruct((nclients, m_grid), jnp.float32))
+
+
+def _gz_out(spec: QSpec, nclients: int):
+    """Out spec + shape of a (K, n) grad-z kernel (window i revisited)."""
+    return (pl.BlockSpec((nclients, spec.window), lambda i, j: (0, i)),
+            jax.ShapeDtypeStruct((nclients, spec.n), jnp.float32))
+
+
+def qz_reconstruct_batched_fwd(spec: QSpec, Z, *, bm: int = DEFAULT_BM,
+                               interpret: bool = False):
+    """Batched Pallas forward: Z (K, n) f32 -> W (K, m) f32 (flat)."""
+    nclients = Z.shape[0]
+    nw, bpw, _ = _grid_dims(spec, bm)
+    out_spec, out_shape = _w_out(spec, nclients, bm)
     out = pl.pallas_call(
-        functools.partial(_bbwd_kernel, spec=spec, bm=bm, nclients=nclients),
+        functools.partial(_bfwd_kernel, spec=spec, bm=bm),
         grid=(nw, bpw),
-        in_specs=[pl.BlockSpec((bm, nclients), lambda i, j: (i * bpw + j, 0))],
-        out_specs=pl.BlockSpec((spec.window, nclients), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((spec.n, nclients), jnp.float32),
+        in_specs=[pl.BlockSpec((nclients, spec.window),
+                               lambda i, j: (0, i))],
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(gt)
-    return out.T
+    )(Z.astype(jnp.float32))
+    return _grid_rows_out(spec, out, bm)
+
+
+def qz_reconstruct_batched_bwd(spec: QSpec, grad_W, *, bm: int = DEFAULT_BM,
+                               interpret: bool = False):
+    """Batched scatter backward: grad_W (K, m) -> grad_Z (K, n) f32."""
+    nclients = grad_W.shape[0]
+    nw, bpw, _ = _grid_dims(spec, bm)
+    out_spec, out_shape = _gz_out(spec, nclients)
+    return pl.pallas_call(
+        functools.partial(_bbwd_kernel, spec=spec, bm=bm),
+        grid=(nw, bpw),
+        in_specs=[pl.BlockSpec((nclients, bm),
+                               lambda i, j: (0, i * bpw + j))],
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(_grid_rows_in(spec, grad_W, bm))
+
+
+def qz_reconstruct_fwd(spec: QSpec, z, *, bm: int = DEFAULT_BM,
+                       interpret: bool = False):
+    """Pallas forward: z (n,) f32 -> w (m,) f32 (flat; caller reshapes)."""
+    return qz_reconstruct_batched_fwd(spec, z[None], bm=bm,
+                                      interpret=interpret)[0]
+
+
+def qz_reconstruct_bwd(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,
+                       interpret: bool = False):
+    """Pallas scatter backward: grad_w (m,) -> grad_z (n,) f32."""
+    return qz_reconstruct_batched_bwd(spec, grad_w.reshape(1, -1), bm=bm,
+                                      interpret=interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -310,102 +257,61 @@ def qz_reconstruct_batched_bwd(spec: QSpec, grad_W, *, bm: int = DEFAULT_BM,
 # ---------------------------------------------------------------------------
 
 def _plan_operands(spec: QSpec, bm: int, order: str):
-    """Block-plan slabs as jnp constants + their shared BlockSpec."""
+    """Block-plan slabs, slot-major ((nw, bpw, deg, window): the window's
+    coordinates on the lanes), as jnp constants + their BlockSpec."""
     plan = build_block_plan(spec, bm, order)
-    bspec = pl.BlockSpec((1, 1, spec.window, plan.deg),
+    bspec = pl.BlockSpec((1, 1, plan.deg, spec.window),
                          lambda i, j: (i, j, 0, 0))
-    return jnp.asarray(plan.rows), jnp.asarray(plan.vals), plan.deg, bspec
-
-
-def _bwd_plan_kernel(g_ref, rows_ref, vals_ref, gz_ref, *, spec: QSpec,
-                     bm: int, deg: int):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        gz_ref[...] = jnp.zeros_like(gz_ref)
-
-    rows = rows_ref[...].reshape(spec.window * deg, 1)  # block-relative
-    onehot = (rows == jax.lax.iota(jnp.int32, bm)[None, :]).astype(
-        jnp.float32
-    )
-    g = g_ref[...].astype(jnp.float32)  # (bm,)
-    # the (window·deg) gather as the one-hot MXU contraction
-    gsel = jnp.dot(onehot, g, preferred_element_type=jnp.float32)
-    vals = vals_ref[...].reshape(spec.window, deg)
-    gz_ref[...] += jnp.sum(vals * gsel.reshape(spec.window, deg), axis=-1)
-
-
-def qz_reconstruct_bwd_plan(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,
-                            interpret: bool = True,
-                            order: str = "canonical"):
-    """Plan-driven Pallas backward: grad_w (m,) -> grad_z (n,) f32."""
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    rows, vals, deg, bspec = _plan_operands(spec, bm, order)
-    g = grad_w.reshape(-1).astype(jnp.float32)
-    g = jnp.pad(g, (0, spec.m_pad - spec.m))
-    if bpw * bm != spec.rows_per_window:
-        g = g.reshape(nw, spec.rows_per_window)
-        g = jnp.pad(g, ((0, 0), (0, bpw * bm - spec.rows_per_window)))
-        g = g.reshape(-1)
-    return pl.pallas_call(
-        functools.partial(_bwd_plan_kernel, spec=spec, bm=bm, deg=deg),
-        grid=(nw, bpw),
-        in_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i * bpw + j,)),
-            bspec, bspec,
-        ],
-        out_specs=pl.BlockSpec((spec.window,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n,), jnp.float32),
-        interpret=interpret,
-    )(g, rows, vals)
+    return (jnp.asarray(np.swapaxes(plan.rows, 2, 3)),
+            jnp.asarray(np.swapaxes(plan.vals, 2, 3)), plan.deg, bspec)
 
 
 def _bbwd_plan_kernel(g_ref, rows_ref, vals_ref, gz_ref, *, spec: QSpec,
-                      bm: int, deg: int, nclients: int):
+                      bm: int, deg: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         gz_ref[...] = jnp.zeros_like(gz_ref)
 
-    rows = rows_ref[...].reshape(spec.window * deg, 1)
-    onehot = (rows == jax.lax.iota(jnp.int32, bm)[None, :]).astype(
-        jnp.float32
-    )
-    g = g_ref[...].astype(jnp.float32)  # (bm, K)
-    # one one-hot, K clients: (window·deg, bm) @ (bm, K)
-    gsel = jnp.dot(onehot, g, preferred_element_type=jnp.float32)
-    vals = vals_ref[...].reshape(spec.window, deg)
-    gz_ref[...] += jnp.sum(
-        vals[:, :, None] * gsel.reshape(spec.window, deg, nclients), axis=1
-    )
+    g = g_ref[...].astype(jnp.float32)  # (K, bm)
+    rows = rows_ref[0, 0]  # (deg, window) block-relative source rows
+    vals = vals_ref[0, 0]
+    src = _iota((bm, spec.window), 0)
+    acc = jnp.zeros(gz_ref.shape, jnp.float32)
+    for e in range(deg):
+        onehot = (src == rows[e:e + 1, :]).astype(jnp.float32)
+        acc = acc + vals[e:e + 1, :] * _dot(g, onehot, HIGHEST)
+    gz_ref[...] += acc
 
 
 def qz_reconstruct_batched_bwd_plan(spec: QSpec, grad_W, *,
                                     bm: int = DEFAULT_BM,
-                                    interpret: bool = True,
+                                    interpret: bool = False,
                                     order: str = "canonical"):
     """Plan-driven batched backward: grad_W (K, m) -> grad_Z (K, n)."""
     nclients = grad_W.shape[0]
-    nw, bpw, m_grid = _grid_dims(spec, bm)
+    nw, bpw, _ = _grid_dims(spec, bm)
     rows, vals, deg, bspec = _plan_operands(spec, bm, order)
-    g = grad_W.reshape(nclients, -1).astype(jnp.float32)
-    g = jnp.pad(g, ((0, 0), (0, spec.m_pad - spec.m)))
-    if bpw * bm != spec.rows_per_window:
-        g = g.reshape(nclients, nw, spec.rows_per_window)
-        g = jnp.pad(g, ((0, 0), (0, 0),
-                        (0, bpw * bm - spec.rows_per_window)))
-    gt = g.reshape(nclients, m_grid).T  # (m_grid, K)
-    out = pl.pallas_call(
-        functools.partial(_bbwd_plan_kernel, spec=spec, bm=bm, deg=deg,
-                          nclients=nclients),
+    out_spec, out_shape = _gz_out(spec, nclients)
+    return pl.pallas_call(
+        functools.partial(_bbwd_plan_kernel, spec=spec, bm=bm, deg=deg),
         grid=(nw, bpw),
         in_specs=[
-            pl.BlockSpec((bm, nclients), lambda i, j: (i * bpw + j, 0)),
+            pl.BlockSpec((nclients, bm), lambda i, j: (0, i * bpw + j)),
             bspec, bspec,
         ],
-        out_specs=pl.BlockSpec((spec.window, nclients), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((spec.n, nclients), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(gt, rows, vals)
-    return out.T
+    )(_grid_rows_in(spec, grad_W, bm), rows, vals)
+
+
+def qz_reconstruct_bwd_plan(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,
+                            interpret: bool = False,
+                            order: str = "canonical"):
+    """Plan-driven Pallas backward: grad_w (m,) -> grad_z (n,) f32."""
+    return qz_reconstruct_batched_bwd_plan(
+        spec, grad_w.reshape(1, -1), bm=bm, interpret=interpret,
+        order=order)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -429,205 +335,182 @@ def _lanes_per_window(spec: QSpec, qbits: int) -> int:
 
 
 def _unpack_window(spec: QSpec, lanes, qbits: int):
-    """In-block lane unpack: (window/wpl,) [or (window/wpl, K)] uint32
-    lanes -> (window,) [or (window, K)] b-bit words — a VMEM-local
-    shift/mask, so the per-coordinate word array only ever exists as
-    this window-sized transient, never as an (n,) slab in HBM
-    (jaxpr-asserted in tests/test_packed_downlink.py)."""
+    """In-block lane unpack: (K, window/wpl) uint32 lanes -> (K, window)
+    b-bit words — a VMEM-local shift/mask, so the per-coordinate word
+    array only ever exists as this window-sized transient, never as an
+    (n,) slab in HBM (jaxpr-asserted in tests/test_packed_downlink.py).
+    Word s of every lane is spread to coordinates ``lane·wpl + s`` by
+    one one-hot MXU contraction per s (exact: b ≤ 16-bit integers at
+    full precision)."""
     wpl = 32 // qbits
+    nl = lanes.shape[-1]
+    coord = _iota((nl, spec.window), 1)
+    own = (coord // wpl) == _iota((nl, spec.window), 0)
     mask = np.uint32((1 << qbits) - 1)
-    sh = np.uint32(qbits) * jax.lax.iota(jnp.uint32, wpl)
-    if lanes.ndim == 2:  # (window/wpl, K) lane slab
-        words = (lanes[:, None, :] >> sh[None, :, None]) & mask
-        return words.reshape(spec.window, lanes.shape[-1])
-    words = (lanes[:, None] >> sh[None, :]) & mask
-    return words.reshape(spec.window)
+    words = jnp.zeros((lanes.shape[0], spec.window), jnp.float32)
+    for s in range(wpl):
+        part = ((lanes >> np.uint32(s * qbits)) & mask).astype(jnp.int32)
+        spread = (own & (coord % wpl == s)).astype(jnp.float32)
+        words = words + _dot(part.astype(jnp.float32), spread, HIGHEST)
+    return words.astype(jnp.int32).astype(jnp.uint32)
 
 
-def _window_mask(spec: QSpec, step, p_win, qbits=None, qpacked=False):
-    """Draw this grid step's z-window in-block from the hash RNG.
+def _window_mask(spec: QSpec, steps, p_win, qbits=None, qpacked=False,
+                 window_id=None):
+    """Draw grid window ``window_id``'s (default ``program_id(0)``)
+    z-bits in-block from the hash RNG: ``steps`` (K, 1) uint32 draw
+    words, ``p_win`` the (K, window) operand -> (K, window) f32 {0,1}.
 
-    ``step`` is the traced uint32 draw-counter word; coordinates are
-    the window's global z indices, so the bits are identical to the
-    oracle's ``sample_mask_hash`` over the full (n,) vector.
-
-    With ``qbits`` the operand is the QUANTIZED probability window
-    (uint32 b-bit words from the downlink codec, ``comm.downlink``)
-    and the draw is the widened-threshold integer compare
-    ``(u >> 8) < quant_threshold_u24(q)`` — pure uint32 shifts and a
-    constant divide, no dequantized f32 probabilities even in-block —
-    bit-identical to the oracle's ``sample_mask_qhash``.  With
-    ``qpacked`` the operand window is the packed uint32 LANES of the
-    sub-byte codecs (``comm.bitpack.pack_words`` layout) and the words
-    are unpacked in-block first (``_unpack_window``).
+    Coordinates are the window's global z indices, so the bits are
+    identical to the oracle's ``sample_mask_hash`` over the full (n,)
+    vector.  With ``qbits`` the operand is the QUANTIZED probability
+    window (uint32 b-bit words from the downlink codec,
+    ``comm.downlink``) and the draw is the widened-threshold integer
+    compare ``(u >> 8) < quant_threshold_u24(q)`` — bit-identical to
+    the oracle's ``sample_mask_qhash``.  With ``qpacked`` the operand
+    window is the packed uint32 LANES of the sub-byte codecs
+    (``comm.bitpack.pack_words`` layout), unpacked in-block first.
     """
     if qpacked:
         p_win = _unpack_window(spec, p_win, qbits)
-    i = pl.program_id(0)
-    coords = i * spec.window + jax.lax.iota(jnp.int32, spec.window)
-    if p_win.ndim == 2:  # (window, K) p-slab: one stream per client
-        u = mask_u32(spec.seed, spec.tensor_id, step[None, :],
-                     coords[:, None])
-    else:
-        u = mask_u32(spec.seed, spec.tensor_id, step, coords)
+    if window_id is None:
+        window_id = pl.program_id(0)
+    coords = window_id * spec.window + _iota((1, spec.window), 1)
+    u = mask_u32(spec.seed, spec.tensor_id, steps, coords)
     if qbits is None:
         return bernoulli_u32(u, p_win.astype(jnp.float32))
     thr = quant_threshold_u24(p_win, qbits)
     return ((u >> np.uint32(8)) < thr).astype(jnp.float32)
 
 
-def _sfwd_kernel(p_ref, step_ref, w_ref, *, spec: QSpec, bm: int, bpw: int,
-                 qbits=None, qpacked=False):
-    idx, vals = _block_rows(spec, bm, masked=False)
-    zwin = _window_mask(spec, step_ref[0], p_ref[...], qbits=qbits,
-                        qpacked=qpacked)
-    zsel = jnp.dot(_onehot(idx, spec.window), zwin,
-                   preferred_element_type=jnp.float32)
-    w_ref[...] = jnp.sum(vals * zsel.reshape(bm, spec.d), axis=-1)
+def _sbfwd_kernel(p_ref, steps_ref, w_ref, *, spec: QSpec, bm: int,
+                  qbits=None, qpacked=False):
+    p_win = p_ref[0] if qpacked else p_ref[...]
+    z = _window_mask(spec, steps_ref[...], p_win, qbits=qbits,
+                     qpacked=qpacked)  # (K, window) {0,1}: exact gather
+    w_ref[...] = _gather_rows(spec, bm, z, None)
 
 
-def qz_sample_reconstruct_fwd(spec: QSpec, p, step, *, bm: int = DEFAULT_BM,
-                              interpret: bool = True, qbits=None,
-                              qpacked=False):
-    """Fused Pallas forward: p (n,) f32 + step word -> w (m,) f32 (flat).
+def _operand(spec: QSpec, P, qbits, qpacked):
+    """The fused forward's probability operand and its BlockSpec.
 
-    With ``qbits`` the operand is the quantized broadcast (b-bit
-    probability words, shipped into the kernel as uint32) and the
-    in-block draw is the widened-threshold integer compare — the
-    dequantized f32 score vector never exists, in HBM or VMEM.  With
-    ``qpacked`` the operand is the (n/wpl,) packed uint32 LANE carry of
-    the sub-byte codecs and each grid step streams ``window/wpl`` whole
-    lanes, unpacking in-block — the per-coordinate word array never
-    materializes outside a window-sized VMEM transient.
+    f32 probabilities, or the codec's words widened to uint32, as the
+    (K, n) slab read one (K, window) block per window.  The packed lane
+    carry is laid out (num_windows, K, window/wpl), so each block is a
+    whole (K, window/wpl) tile however few lanes a window holds.
     """
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    op_len = _lanes_per_window(spec, qbits) if qpacked else spec.window
-    operand = (p.astype(jnp.float32) if qbits is None
-               else jnp.asarray(p).astype(jnp.uint32))
-    out = pl.pallas_call(
-        functools.partial(_sfwd_kernel, spec=spec, bm=bm, bpw=bpw,
-                          qbits=qbits, qpacked=qpacked),
-        grid=(nw, bpw),
-        in_specs=[
-            pl.BlockSpec((op_len,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm,), lambda i, j: (i * bpw + j,)),
-        out_shape=jax.ShapeDtypeStruct((m_grid,), jnp.float32),
-        interpret=interpret,
-    )(operand, jnp.asarray(step, jnp.uint32).reshape(1))
-    if bpw * bm != spec.rows_per_window:
-        out = out.reshape(nw, bpw * bm)[:, : spec.rows_per_window].reshape(-1)
-    return out[: spec.m]
+    nclients = P.shape[0]
+    if qbits is None:
+        P = jnp.asarray(P).astype(jnp.float32)
+    else:
+        P = jnp.asarray(P).astype(jnp.uint32)
+    if not qpacked:
+        return P, pl.BlockSpec((nclients, spec.window), lambda i, j: (0, i))
+    nl = _lanes_per_window(spec, qbits)
+    P = jnp.transpose(P.reshape(nclients, spec.num_windows, nl), (1, 0, 2))
+    return P, pl.BlockSpec((1, nclients, nl), lambda i, j: (i, 0, 0))
 
 
-def _sbfwd_kernel(pt_ref, steps_ref, w_ref, *, spec: QSpec, bm: int,
-                  nclients: int, qbits=None, qpacked=False):
-    idx, vals = _block_rows(spec, bm, masked=False)
-    slab = _window_mask(spec, steps_ref[...], pt_ref[...],
-                        qbits=qbits, qpacked=qpacked)  # (window, K)
-    zsel = jnp.dot(_onehot(idx, spec.window), slab,
-                   preferred_element_type=jnp.float32)
-    w_ref[...] = jnp.sum(
-        vals[..., None] * zsel.reshape(bm, spec.d, nclients), axis=1
-    )
+def _steps_col(steps, nclients: int):
+    """The (K, 1) uint32 draw-word column every fused kernel reads."""
+    return jnp.broadcast_to(jnp.asarray(steps, jnp.uint32).reshape(-1, 1),
+                            (nclients, 1))
 
 
 def qz_sample_reconstruct_batched_fwd(spec: QSpec, P, steps, *,
                                       bm: int = DEFAULT_BM,
-                                      interpret: bool = True, qbits=None,
+                                      interpret: bool = False, qbits=None,
                                       qpacked=False):
     """Fused batched forward: P (K, n) probs + steps (K,) -> W (K, m).
 
-    ``qbits``/``qpacked``: as ``qz_sample_reconstruct_fwd`` — P is the
-    (K, n) quantized word slab (or the (K, n/wpl) packed lane slab) and
-    the draw stays integer in-block.
+    With ``qbits`` P is the (K, n) quantized word slab (b-bit
+    probability words, shipped into the kernel as uint32) and the
+    in-block draw is the widened-threshold integer compare — the
+    dequantized f32 score vector never exists, in HBM or VMEM.  With
+    ``qpacked`` P is the (K, n/wpl) packed uint32 LANE slab of the
+    sub-byte codecs and each grid step streams ``window/wpl`` whole
+    lanes, unpacking in-block.
     """
     nclients = P.shape[0]
-    nw, bpw, m_grid = _grid_dims(spec, bm)
-    op_len = _lanes_per_window(spec, qbits) if qpacked else spec.window
-    if qbits is None:
-        pt = P.astype(jnp.float32).T  # (n, K) — window-major p-slabs
-    else:
-        pt = jnp.asarray(P).astype(jnp.uint32).T
+    nw, bpw, _ = _grid_dims(spec, bm)
+    operand, op_spec = _operand(spec, P, qbits, qpacked)
+    out_spec, out_shape = _w_out(spec, nclients, bm)
     out = pl.pallas_call(
-        functools.partial(_sbfwd_kernel, spec=spec, bm=bm,
-                          nclients=nclients, qbits=qbits, qpacked=qpacked),
+        functools.partial(_sbfwd_kernel, spec=spec, bm=bm, qbits=qbits,
+                          qpacked=qpacked),
         grid=(nw, bpw),
-        in_specs=[
-            pl.BlockSpec((op_len, nclients), lambda i, j: (i, 0)),
-            pl.BlockSpec((nclients,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, nclients), lambda i, j: (i * bpw + j, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_grid, nclients), jnp.float32),
+        in_specs=[op_spec, pl.BlockSpec((nclients, 1), lambda i, j: (0, 0))],
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
-    )(pt, jnp.asarray(steps, jnp.uint32))
-    if bpw * bm != spec.rows_per_window:
-        out = out.reshape(nw, bpw * bm, nclients)[
-            :, : spec.rows_per_window
-        ].reshape(-1, nclients)
-    return out[: spec.m].T
+    )(operand, _steps_col(steps, nclients))
+    return _grid_rows_out(spec, out, bm)
 
 
-def _pack_shifts():
-    return jax.lax.iota(jnp.uint32, 32)
+def qz_sample_reconstruct_fwd(spec: QSpec, p, step, *, bm: int = DEFAULT_BM,
+                              interpret: bool = False, qbits=None,
+                              qpacked=False):
+    """Fused Pallas forward: p (n,) + step word -> w (m,) f32 (flat);
+    ``qbits``/``qpacked`` as ``qz_sample_reconstruct_batched_fwd``."""
+    return qz_sample_reconstruct_batched_fwd(
+        spec, jnp.asarray(p)[None], step, bm=bm, interpret=interpret,
+        qbits=qbits, qpacked=qpacked)[0]
 
 
-def _spack_kernel(p_ref, step_ref, lanes_ref, *, spec: QSpec):
-    zwin = _window_mask(spec, step_ref[0], p_ref[...].astype(jnp.float32))
-    bits = zwin.astype(jnp.uint32).reshape(spec.window // 32, 32)
-    lanes_ref[...] = jnp.sum(bits << _pack_shifts(), axis=-1,
-                             dtype=jnp.uint32)
+def _pack_lanes(bits, window: int):
+    """(K, window) f32 {0,1} -> (K, window/32) uint32 lanes, bit j of
+    lane i = coordinate 32i+j.  Each 16-bit half is one MXU contraction
+    against a (window, window/32) matrix of distinct powers of two, so
+    every sum is an exact integer below 2^16."""
+    shape = (window, window // 32)
+    coord = _iota(shape, 0)
+    own = (coord // 32) == _iota(shape, 1)
+    bit = coord % 32
+
+    def half(lo: int):
+        sel = own & (bit >= lo) & (bit < lo + 16)
+        weight = jnp.where(sel, jnp.left_shift(1, (bit - lo) & 15), 0)
+        return _dot(bits, weight.astype(jnp.float32)).astype(jnp.int32)
+
+    word = half(0) | jnp.left_shift(half(16), 16)
+    return jax.lax.bitcast_convert_type(word, jnp.uint32)
 
 
-def qz_sample_pack_fwd(spec: QSpec, p, step, *, interpret: bool = True):
-    """Fused upload draw: p (n,) -> (n/32,) uint32 wire lanes.
-
-    Lane layout is exactly ``comm.bitpack.pack_mask`` (bit j of lane i
-    = coordinate 32i+j).  Requires ``spec.window % 32 == 0`` so each
-    grid step emits whole lanes (``ops.sample_pack`` falls back to the
-    jnp oracle otherwise).
-    """
-    assert spec.window % 32 == 0, "pallas sample_pack needs window % 32 == 0"
-    out = pl.pallas_call(
-        functools.partial(_spack_kernel, spec=spec),
-        grid=(spec.num_windows,),
-        in_specs=[
-            pl.BlockSpec((spec.window,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((spec.window // 32,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n // 32,), jnp.uint32),
-        interpret=interpret,
-    )(p.astype(jnp.float32), jnp.asarray(step, jnp.uint32).reshape(1))
-    return out
-
-
-def _sbpack_kernel(pt_ref, steps_ref, lanes_ref, *, spec: QSpec,
-                   nclients: int):
-    slab = _window_mask(spec, steps_ref[...],
-                        pt_ref[...].astype(jnp.float32))  # (window, K)
-    bits = slab.astype(jnp.uint32).reshape(spec.window // 32, 32, nclients)
-    lanes_ref[...] = jnp.sum(bits << _pack_shifts()[None, :, None], axis=1,
-                             dtype=jnp.uint32)
+def _sbpack_kernel(p_ref, steps_ref, lanes_ref, *, spec: QSpec):
+    z = _window_mask(spec, steps_ref[...], p_ref[...].astype(jnp.float32))
+    lanes_ref[0] = _pack_lanes(z, spec.window)
 
 
 def qz_sample_pack_batched_fwd(spec: QSpec, P, steps, *,
-                               interpret: bool = True):
-    """Fused batched upload draw: P (K, n) -> (K, n/32) uint32 lanes."""
+                               interpret: bool = False):
+    """Fused batched upload draw: P (K, n) -> (K, n/32) uint32 lanes.
+
+    Lane layout is exactly ``comm.bitpack.pack_mask``.  Requires
+    ``spec.window % 32 == 0`` so each grid step emits whole lanes
+    (``ops.sample_pack`` falls back to the jnp oracle otherwise).  The
+    kernel writes a (num_windows, K, window/32) slab — one full
+    (K, window/32) tile per grid step — that the wrapper lays out as
+    (K, n/32).
+    """
     assert spec.window % 32 == 0, "pallas sample_pack needs window % 32 == 0"
     nclients = P.shape[0]
-    pt = P.astype(jnp.float32).T  # (n, K)
+    nl = spec.window // 32
     out = pl.pallas_call(
-        functools.partial(_sbpack_kernel, spec=spec, nclients=nclients),
+        functools.partial(_sbpack_kernel, spec=spec),
         grid=(spec.num_windows,),
         in_specs=[
-            pl.BlockSpec((spec.window, nclients), lambda i: (i, 0)),
-            pl.BlockSpec((nclients,), lambda i: (0,)),
+            pl.BlockSpec((nclients, spec.window), lambda i: (0, i)),
+            pl.BlockSpec((nclients, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((spec.window // 32, nclients),
-                               lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((spec.n // 32, nclients), jnp.uint32),
+        out_specs=pl.BlockSpec((1, nclients, nl), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((spec.num_windows, nclients, nl),
+                                       jnp.uint32),
         interpret=interpret,
-    )(pt, jnp.asarray(steps, jnp.uint32))
-    return out.T
+    )(jnp.asarray(P).astype(jnp.float32), _steps_col(steps, nclients))
+    return jnp.transpose(out, (1, 0, 2)).reshape(nclients, spec.n // 32)
+
+
+def qz_sample_pack_fwd(spec: QSpec, p, step, *, interpret: bool = False):
+    """Fused upload draw: p (n,) -> (n/32,) uint32 wire lanes."""
+    return qz_sample_pack_batched_fwd(spec, jnp.asarray(p)[None], step,
+                                      interpret=interpret)[0]

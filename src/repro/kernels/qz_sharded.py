@@ -12,12 +12,10 @@ with the sharding-major layout (QSpec.major_axis/shard_count):
    local reshape+moveaxis emits the weight block in consumer layout and
    ``out_specs`` reassembles the global tensor with ZERO collectives.
 
-The shard_map is entered without an explicit mesh so it composes with
-the (partially-manual) context mesh of the federated round.  The
-jax-version compat (top-level ``jax.shard_map`` vs the experimental API
-bound to the ambient ``with mesh:`` context) is shared with the
-transport collectives — ``repro.comm.shardmap.shard_map_compat`` — so
-the op is exercisable on forced-multi-device CPU too.
+The shard_map is entered without an explicit mesh
+(``repro.comm.shardmap.shard_map``) so it composes with the context
+mesh: the caller's ``jax.set_mesh``, or the partially-manual mesh of a
+sharded federated round.  Forced-multi-device CPU runs the same path.
 
 Batched variants (``sharded_reconstruct_batched`` /
 ``sharded_grad_z_batched``): K stacked clients share one generation of
@@ -46,8 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..comm.shardmap import shard_map_compat
-from ..core.qspec import QSpec, row_indices, row_values
+from ..comm.shardmap import shard_map
+from ..core.qspec import QSpec, edge_sum, row_indices, row_values
 from ..core.transpose_plan import (
     build_transpose_plan,
     plan_window_apply,
@@ -61,8 +59,8 @@ TARGET_CHUNK_BYTES = 128 << 20  # bound the (rows, d) temporaries
 
 
 def _shard_map(f, in_specs, out_specs):
-    """The shared compat shim bound to this module's 'model' axis."""
-    return shard_map_compat(f, (AXIS,), in_specs, out_specs)
+    """``comm.shardmap.shard_map`` bound to this module's 'model' axis."""
+    return shard_map(f, (AXIS,), in_specs, out_specs)
 
 
 def _num_chunks(spec: QSpec, nclients: int = 1) -> int:
@@ -127,7 +125,7 @@ def sharded_reconstruct(spec: QSpec, z, ms: int):
 
         def one(c):
             gidx, vals = _chunk_rows(spec, c, rpc)
-            return jnp.sum(vals * zf[gidx], axis=-1)
+            return edge_sum(vals * zf[gidx])
 
         w = jax.lax.map(one, jnp.arange(nc)).reshape(-1)[: spec.m_blk]
         return jnp.moveaxis(w.reshape(loc_moved), 0, a)
@@ -157,7 +155,7 @@ def sharded_reconstruct_batched(spec: QSpec, Z, ms: int):
         def one(c):
             gidx, vals = _chunk_rows(spec, c, rpc)
             return jax.lax.map(
-                lambda z: jnp.sum(vals * z[gidx], axis=-1), zf
+                lambda z: edge_sum(vals * z[gidx]), zf
             )  # (K, rpc)
 
         w = jax.lax.map(one, jnp.arange(nc))  # (nc, K, rpc)
@@ -237,7 +235,7 @@ def sharded_sample_reconstruct(spec: QSpec, p, step, ms: int, qbits=None,
 
         def one(c):
             gidx, vals = _chunk_rows(spec, c, rpc)
-            return jnp.sum(vals * zf[gidx], axis=-1)
+            return edge_sum(vals * zf[gidx])
 
         w = jax.lax.map(one, jnp.arange(nc)).reshape(-1)[: spec.m_blk]
         return jnp.moveaxis(w.reshape(loc_moved), 0, a)
@@ -275,7 +273,7 @@ def sharded_sample_reconstruct_batched(spec: QSpec, Pr, steps, ms: int,
         def one(c):
             gidx, vals = _chunk_rows(spec, c, rpc)
             return jax.lax.map(
-                lambda z: jnp.sum(vals * z[gidx], axis=-1), zf
+                lambda z: edge_sum(vals * z[gidx]), zf
             )  # (K, rpc)
 
         w = jax.lax.map(one, jnp.arange(nc))  # (nc, K, rpc)

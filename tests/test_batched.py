@@ -9,12 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # vendored fallback: fixed-seed examples, no shrinking
-    from _hypothesis_fallback import given, settings
-    from _hypothesis_fallback import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.bitpack import pack_mask, packed_len, unpack_mask
 from repro.core.qspec import make_qspec

@@ -17,12 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # vendored fallback: fixed-seed examples, no shrinking
-    from _hypothesis_fallback import given, settings
-    from _hypothesis_fallback import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from jax.sharding import PartitionSpec as P
 
@@ -36,7 +32,7 @@ from repro.comm.bitpack import (
 )
 from repro.comm.metering import mask_uplink_bytes, round_wire_report, wire_table
 from repro.comm.protocol import get_transport, resolve_transport, transport_names
-from repro.comm.shardmap import shard_map_compat
+from repro.comm.shardmap import shard_map
 from repro.core import FederatedConfig, ZamplingConfig, build_specs, init_state
 from repro.core.federated import WIRE_METRIC_KEYS, federated_round, sharded_client_update
 from repro.data import client_batch_stream, iid_client_split, make_teacher_dataset
@@ -247,9 +243,9 @@ class TestCollectivePath:
             s_pop = packed_popcount_sum(lanes, n)
             return s_f32[None], s_u32[None], s_pop[None]
 
-        with mesh:
-            f = shard_map_compat(body, ("data",), P("data", None),
-                                 (P(None, None),) * 3)
+        with jax.set_mesh(mesh):
+            f = shard_map(body, ("data",), P("data", None),
+                          (P(None, None),) * 3)
             s_f32, s_u32, s_pop = jax.jit(f)(Z)
         want = np.asarray(Z).sum(0)
         np.testing.assert_array_equal(np.asarray(s_f32)[0], want)
@@ -271,9 +267,9 @@ class TestCollectivePath:
             def body(zl, t=t):
                 return t.aggregate_collective(zl[0], ("data",))[None]
 
-            with mesh:
-                f = shard_map_compat(body, ("data",), P("data", None),
-                                     P(None, None))
+            with jax.set_mesh(mesh):
+                f = shard_map(body, ("data",), P("data", None),
+                              P(None, None))
                 outs[s] = np.asarray(jax.jit(f)(Z))[0]
         for s in STRATEGIES[1:]:
             np.testing.assert_array_equal(outs["mean_f32"], outs[s])
@@ -297,10 +293,10 @@ class TestCollectivePath:
                 return sharded_client_update(zspecs, st, mlp_loss, b, k,
                                              cfg)
 
-            with mesh:
-                f = shard_map_compat(body, ("data",),
-                                     (state_specs, P("data"), P()),
-                                     (state_specs, met_specs))
+            with jax.set_mesh(mesh):
+                f = shard_map(body, ("data",),
+                              (state_specs, P("data"), P()),
+                              (state_specs, met_specs))
                 ns, met = jax.jit(f)(state, batch, jax.random.PRNGKey(0))
             outs[s] = jax.tree.map(np.asarray, ns["scores"])
             assert np.isfinite(float(met["loss"]))
@@ -323,10 +319,10 @@ class TestCollectivePath:
             b = jax.tree.map(lambda x: x[0], b)
             return sharded_client_update(zspecs, st, mlp_loss, b, k, cfg)
 
-        with mesh:
-            f = shard_map_compat(body, ("data",),
-                                 (state_specs, P("data"), P()),
-                                 (state_specs, met_specs))
+        with jax.set_mesh(mesh):
+            f = shard_map(body, ("data",),
+                          (state_specs, P("data"), P()),
+                          (state_specs, met_specs))
             _, met = jax.jit(f)(state, batch, jax.random.PRNGKey(0))
         assert float(met["uplink_bytes_round"]) == 4 * float(
             met["uplink_bytes_per_client"]

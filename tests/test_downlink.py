@@ -44,7 +44,7 @@ from repro.comm.metering import (
     score_downlink_bytes,
     wire_table,
 )
-from repro.comm.shardmap import shard_map_compat
+from repro.comm.shardmap import shard_map
 from repro.core import (
     FederatedConfig,
     ZamplingConfig,
@@ -609,10 +609,10 @@ class TestFederatedRounds:
             b = jax.tree.map(lambda x: x[0], b)
             return sharded_client_update(zspecs, s, mlp_loss, b, k, cfg)
 
-        with mesh:
-            f = shard_map_compat(body, ("data",),
-                                 (state_specs, P("data"), P()),
-                                 (state_specs, met_specs))
+        with jax.set_mesh(mesh):
+            f = shard_map(body, ("data",),
+                          (state_specs, P("data"), P()),
+                          (state_specs, met_specs))
             got, _ = jax.jit(f)(st, batch, jax.random.PRNGKey(0))
         for p in want["scores"]:
             assert got["scores"][p].dtype == jnp.uint8

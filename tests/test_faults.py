@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from _helpers import data_mesh_or_skip, round_metric_specs
 
-from repro.comm import get_transport, shard_map_compat
+from repro.comm import get_transport, shard_map
 from repro.comm.bitpack import pack_mask, packed_weighted_sum
 from repro.core import FederatedConfig, ZamplingConfig, build_specs, init_state
 from repro.core.federated import (
@@ -129,10 +129,10 @@ def _sharded_round(mesh, zspecs, state, batch, key, cfg, *, ids=None,
     if weights is not None:
         in_specs.append(P("data"))
         args.append(jnp.asarray(weights, jnp.uint32))
-    with mesh:
-        f = shard_map_compat(body, ("data",), tuple(in_specs),
-                             (jax.tree.map(lambda _: P(), state),
-                              round_metric_specs()))
+    with jax.set_mesh(mesh):
+        f = shard_map(body, ("data",), tuple(in_specs),
+                      (jax.tree.map(lambda _: P(), state),
+                       round_metric_specs()))
         return jax.jit(f)(*args)
 
 
@@ -496,8 +496,8 @@ def test_sharded_fit_threads_participation(setup):
                                   client_ids=i[:, 0], weights=ww[:, 0],
                                   faults=PLAN)
 
-    with mesh:
-        f = shard_map_compat(
+    with jax.set_mesh(mesh):
+        f = shard_map(
             body, ("data",),
             (state_specs, P(None, "data"), P(), P(None, "data"),
              P(None, "data")),

@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from _helpers import data_mesh_or_skip, round_metric_specs
 
-from repro.comm import shard_map_compat
+from repro.comm import shard_map
 from repro.core import FederatedConfig, ZamplingConfig, build_specs, init_state
 from repro.core.federated import (
     WIRE_METRIC_KEYS,
@@ -153,10 +153,10 @@ def test_sharded_fit_matches_sequential(setup):
         b = jax.tree.map(lambda x: x[0], b)  # (R, E, B, ...)
         return sharded_client_fit(zspecs, s, mlp_loss, b, k, cfg)
 
-    with mesh:
-        f = shard_map_compat(fit_body, ("data",),
-                             (state_specs, P("data"), P()),
-                             (state_specs, met_specs))
+    with jax.set_mesh(mesh):
+        f = shard_map(fit_body, ("data",),
+                      (state_specs, P("data"), P()),
+                      (state_specs, met_specs))
         st_fit, mets = jax.jit(f)(state, rb, key)
     assert mets["loss"].shape == (R,)
 
@@ -167,10 +167,10 @@ def test_sharded_fit_matches_sequential(setup):
 
     st_seq = state
     for r, sub in enumerate(jax.random.split(key, R)):
-        with mesh:
-            f2 = shard_map_compat(round_body, ("data",),
-                                  (state_specs, P("data"), P(), P()),
-                                  (state_specs, met_specs))
+        with jax.set_mesh(mesh):
+            f2 = shard_map(round_body, ("data",),
+                           (state_specs, P("data"), P(), P()),
+                           (state_specs, met_specs))
             b = jax.tree.map(lambda x, r=r: x[:, r], rb)
             st_seq, _ = jax.jit(f2)(st_seq, b, sub, jnp.uint32(r))
     for p in st_fit["scores"]:

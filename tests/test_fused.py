@@ -23,7 +23,7 @@ from _helpers import data_mesh_or_skip, round_metric_specs
 
 from repro.comm.bitpack import pack_mask, packed_len
 from repro.comm.metering import round_wire_report
-from repro.comm.shardmap import shard_map_compat
+from repro.comm.shardmap import shard_map
 from repro.core import FederatedConfig, ZamplingConfig, build_specs, init_state
 from repro.core.federated import federated_round, local_update, sharded_client_update
 from repro.core.qspec import make_qspec
@@ -413,10 +413,10 @@ def test_sharded_fused_equals_vmap_and_composed(fed_setup):
                 return sharded_client_update(zspecs, st, mlp_loss, b, k,
                                              cfg)
 
-            with mesh:
-                f = shard_map_compat(body, ("data",),
-                                     (state_specs, P("data"), P()),
-                                     (state_specs, met_specs))
+            with jax.set_mesh(mesh):
+                f = shard_map(body, ("data",),
+                              (state_specs, P("data"), P()),
+                              (state_specs, met_specs))
                 ns, _ = jax.jit(f)(state, batch, jax.random.PRNGKey(0))
             for p in base:
                 np.testing.assert_array_equal(
@@ -436,7 +436,7 @@ def test_fused_model_sharded_dispatch():
     p = _probs(spec, seed=13)
     step = jnp.uint32(2)
     mesh = jax.make_mesh((4,), ("model",))
-    with mesh:
+    with jax.set_mesh(mesh):
         got = np.asarray(
             ops.sample_reconstruct(spec, p, step, model_size=4))
         z = sample_mask_hash(p, spec.seed, spec.tensor_id, step)

@@ -45,7 +45,7 @@ from repro.comm.bitpack import (
 )
 from repro.comm.downlink import codec_for_dtype, get_codec
 from repro.comm.metering import scheduled_downlink_bits
-from repro.comm.shardmap import shard_map_compat
+from repro.comm.shardmap import shard_map
 from repro.core import (
     FederatedConfig,
     ZamplingConfig,
@@ -501,10 +501,10 @@ class TestPackedRounds:
                 return sharded_client_update(zspecs, s, mlp_loss, b, k,
                                              cfg)
 
-            with mesh:
-                f = shard_map_compat(body, ("data",),
-                                     (state_specs, P("data"), P()),
-                                     (state_specs, round_metric_specs()))
+            with jax.set_mesh(mesh):
+                f = shard_map(body, ("data",),
+                              (state_specs, P("data"), P()),
+                              (state_specs, round_metric_specs()))
                 outs[tag], _ = jax.jit(f)(st, batch0,
                                           jax.random.PRNGKey(0))
         vm, _ = jax.jit(

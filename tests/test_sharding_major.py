@@ -80,7 +80,7 @@ class TestShardMapPath:
         spec = self._spec()
         z = jnp.asarray(np.random.RandomState(0).rand(spec.n), jnp.float32)
         q = np.asarray(materialize_q(spec))
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             got = np.asarray(sharded_reconstruct(spec, z, 4))
         np.testing.assert_allclose(
             got, (q @ np.asarray(z)).reshape(spec.shape), rtol=1e-5,
@@ -98,7 +98,7 @@ class TestShardMapPath:
         Z = jnp.asarray(np.random.RandomState(1).rand(k, spec.n),
                         jnp.float32)
         q = np.asarray(materialize_q(spec))
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             got = np.asarray(sharded_reconstruct_batched(spec, Z, 4))
         want = np.einsum("mn,kn->km", q, np.asarray(Z)).reshape(
             k, *spec.shape
@@ -106,7 +106,7 @@ class TestShardMapPath:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
         G = jnp.asarray(np.random.RandomState(2).randn(k, *spec.shape),
                         jnp.float32)
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             got_g = np.asarray(sharded_grad_z_batched(spec, G, 4))
         want_g = np.einsum("mn,km->kn", q, np.asarray(G).reshape(k, -1))
         np.testing.assert_allclose(got_g, want_g, rtol=1e-4, atol=1e-4)
@@ -118,7 +118,7 @@ class TestShardMapPath:
         Z = jnp.asarray(np.random.RandomState(3).rand(2, spec.n),
                         jnp.float32)
         want = np.asarray(reconstruct_ref(spec, Z[0]))
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             got = np.asarray(ops.reconstruct(spec, Z[0], model_size=4))
             got_b = np.asarray(
                 ops.reconstruct_batched(spec, Z, model_size=4)
@@ -147,7 +147,7 @@ class TestShardedLocalDraw:
         spec = self._spec()
         p = jnp.asarray(np.random.RandomState(0).rand(spec.n), jnp.float32)
         step = jnp.uint32(77)
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             z = sample_mask_hash(p, spec.seed, spec.tensor_id, step)
             want = np.asarray(sharded_reconstruct(spec, z, 4))
             got = np.asarray(sharded_sample_reconstruct(spec, p, step, 4))
@@ -169,7 +169,7 @@ class TestShardedLocalDraw:
         steps = jnp.arange(10, 10 + k, dtype=jnp.uint32)
         q = jnp.asarray((np.random.RandomState(2).rand(spec.n) * 255)
                         .astype(np.uint8))
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             Z = sample_mask_hash(Pr, spec.seed, spec.tensor_id, steps)
             want_b = np.asarray(sharded_reconstruct_batched(spec, Z, 4))
             got_b = np.asarray(
@@ -194,7 +194,7 @@ class TestShardedLocalDraw:
         Pr = jnp.asarray(np.random.RandomState(3).rand(2, spec.n),
                          jnp.float32)
         steps = jnp.asarray([4, 9], jnp.uint32)
-        with _model_mesh():
+        with jax.set_mesh(_model_mesh()):
             Z = sample_mask_hash(Pr, spec.seed, spec.tensor_id, steps)
             want = np.asarray(sharded_reconstruct(spec, Z[0], 4))
             want_b = np.asarray(sharded_reconstruct_batched(spec, Z, 4))

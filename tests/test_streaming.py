@@ -172,7 +172,7 @@ def test_streaming_skips_below_min_clients(setup):
 # ---------------------------------------------------------------------------
 
 def test_streaming_vmap_matches_shard_map_slab(setup):
-    from repro.comm import shard_map_compat
+    from repro.comm import shard_map
     from repro.core.federated import sharded_client_update
     from jax.sharding import PartitionSpec as P
 
@@ -194,8 +194,8 @@ def test_streaming_vmap_matches_shard_map_slab(setup):
                                      cfg, faults=PLAN, client_id=i[0],
                                      weight=ww[0])
 
-    with mesh:
-        f = shard_map_compat(
+    with jax.set_mesh(mesh):
+        f = shard_map(
             body, ("data",),
             (state_specs, P("data"), P(), P("data"), P("data")),
             (state_specs, round_metric_specs()))

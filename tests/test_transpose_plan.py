@@ -237,7 +237,7 @@ def test_sharded_plan_matches_scatter_and_global(monkeypatch):
     spec = make_qspec(0, (8, 6, 16), 16, compression=2.0, d=4, window=32,
                       seed=3, major_axis=2, shard_count=4)
     g, G = _g(spec, seed=12), _g(spec, seed=13, k=3)
-    with mesh:
+    with jax.set_mesh(mesh):
         got = np.asarray(sharded_grad_z(spec, g, 4))
         gotb = np.asarray(sharded_grad_z_batched(spec, G, 4))
         monkeypatch.setenv("REPRO_BWD_PLAN", "scatter")
