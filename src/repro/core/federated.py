@@ -146,6 +146,8 @@ from ..comm.metering import (
 )
 from ..comm.protocol import resolve_transport, transport_names
 from ..optim import Optimizer, sgd
+from ..tracing import (FED_AGGREGATE, FED_DOWNLINK, FED_MODEL, FED_UPDATE,
+                       FED_UPLOAD)
 from .sampling import (as_word, clip_probs, fold_word,
                        quant_threshold_u24_dyn)
 from .zampling import (
@@ -381,24 +383,28 @@ def local_update(
     opt = opt or sgd(cfg.local_lr)
     program = mask_program(zspecs, cfg)
     kw = as_word(key)
-    scores0 = program.decode_scores(state["scores"])
+    with jax.named_scope(FED_DOWNLINK):
+        scores0 = program.decode_scores(state["scores"])
     dense0 = dict(state["dense"])
 
     def loss_of(trainable, batch, step_word):
+        # the weights call holds the reconstruct kernel: no scope around it
         params = program.weights(
             trainable["scores"], trainable["dense"], step_word,
             constraints=constraints, row_sharding=row_sharding,
         )
-        return loss_fn(params, batch)
+        with jax.named_scope(FED_MODEL):
+            return loss_fn(params, batch)
 
     def step(carry, xs):
         trainable, opt_state = carry
         batch, e = xs
-        loss, grads = jax.value_and_grad(loss_of)(
-            trainable, batch, fold_word(kw, e)
-        )
-        updates, opt_state = opt.update(grads, opt_state, trainable)
-        trainable = jax.tree.map(lambda p, u: p + u, trainable, updates)
+        with jax.named_scope(FED_MODEL):
+            word = fold_word(kw, e)
+        loss, grads = jax.value_and_grad(loss_of)(trainable, batch, word)
+        with jax.named_scope(FED_UPDATE):
+            updates, opt_state = opt.update(grads, opt_state, trainable)
+            trainable = jax.tree.map(lambda p, u: p + u, trainable, updates)
         return (trainable, opt_state), loss
 
     trainable0 = {"scores": scores0, "dense": dense0}
@@ -410,8 +416,9 @@ def local_update(
     # p_new = f(s_new); z_new ~ Bern(p_new) — the n bits sent upstream,
     # drawn at the next counter value (E) and emitted as wire lanes on
     # the packed transports (fused: in-kernel, no f32 mask slab).
-    z_new = program.upload(trainable["scores"],
-                           fold_word(kw, cfg.local_steps))
+    with jax.named_scope(FED_UPLOAD):
+        z_new = program.upload(trainable["scores"],
+                               fold_word(kw, cfg.local_steps))
     return z_new, trainable["dense"], jnp.mean(losses)
 
 
@@ -490,6 +497,7 @@ def _full_participation_metrics(k: int):
     }
 
 
+@jax.named_scope(FED_DOWNLINK)
 def _encode_scores(zspecs: ZamplingSpecs, cfg: FederatedConfig,
                    scores, key, round_index, b_vec=None):
     """Re-encode the aggregated p(t+1) as the next round's broadcast.
@@ -522,6 +530,7 @@ def _encode_scores(zspecs: ZamplingSpecs, cfg: FederatedConfig,
     }
 
 
+@jax.named_scope(FED_DOWNLINK)
 def _round_b_vec(zspecs: ZamplingSpecs, cfg: FederatedConfig, state,
                  round_index):
     """This round's per-tensor downlink width vector (traced uint32),
@@ -602,6 +611,7 @@ def _frontier_next_b(zspecs: ZamplingSpecs, cfg: FederatedConfig,
     return jnp.stack(nxt)
 
 
+@jax.named_scope(FED_DOWNLINK)
 def _schedule_state_out(zspecs: ZamplingSpecs, cfg: FederatedConfig,
                         agg, state, b_vec, skip=None):
     """The extra carried leaves of a scheduled round's output state
@@ -760,81 +770,82 @@ def _streaming_round(zspecs, state, loss_fn, client_batches, key, cfg,
     def body(carry, x):
         words = fold_word(as_word(key), rword, x["ids"])
         z_all, dense_all, losses = jax.vmap(one)(x["batches"], words)
-        z_wire, codes, arrived, participating = _resolve_faults(
-            zspecs, packed, z_all, faults, round_index, x["ids"])
-        chunk_live = x["live"]
-        participating = participating & chunk_live
-        w_eff = x["w"] * participating.astype(jnp.uint32)
-        if packed:
-            votes = {
-                p: transport.fold_stacked_packed_weighted(
-                    carry["votes"][p], z_wire[p], zspecs.specs[p].n,
-                    w_eff)
-                for p in z_wire
-            }
-        else:
-            votes = {
-                p: transport.fold_stacked_weighted(carry["votes"][p], z,
-                                                   w_eff)
-                for p, z in z_wire.items()
-            }
-        w_f = w_eff.astype(jnp.float32)
+        with jax.named_scope(FED_AGGREGATE):
+            z_wire, codes, arrived, participating = _resolve_faults(
+                zspecs, packed, z_all, faults, round_index, x["ids"])
+            chunk_live = x["live"]
+            participating = participating & chunk_live
+            w_eff = x["w"] * participating.astype(jnp.uint32)
+            if packed:
+                votes = {
+                    p: transport.fold_stacked_packed_weighted(
+                        carry["votes"][p], z_wire[p], zspecs.specs[p].n,
+                        w_eff)
+                    for p in z_wire
+                }
+            else:
+                votes = {
+                    p: transport.fold_stacked_weighted(carry["votes"][p], z,
+                                                       w_eff)
+                    for p, z in z_wire.items()
+                }
+            w_f = w_eff.astype(jnp.float32)
 
-        def dense_fold(acc, d):
-            wcol = w_f.reshape((chunk,) + (1,) * (d.ndim - 1))
-            return acc + jnp.sum(d * wcol, axis=0)
+            def dense_fold(acc, d):
+                wcol = w_f.reshape((chunk,) + (1,) * (d.ndim - 1))
+                return acc + jnp.sum(d * wcol, axis=0)
 
-        counts = _fault_counts(codes, arrived, participating,
-                               live=chunk_live)
-        new = {
-            "votes": votes,
-            "dense": jax.tree.map(dense_fold, carry["dense"], dense_all),
-            "wsum": carry["wsum"] + jnp.sum(w_eff, dtype=jnp.uint32),
-            "loss": carry["loss"] + jnp.sum(
-                losses * participating.astype(jnp.float32)),
-            **{c: carry[c] + counts[c] for c in _STREAM_COUNTER_KEYS},
-        }
+            counts = _fault_counts(codes, arrived, participating,
+                                   live=chunk_live)
+            new = {
+                "votes": votes,
+                "dense": jax.tree.map(dense_fold, carry["dense"], dense_all),
+                "wsum": carry["wsum"] + jnp.sum(w_eff, dtype=jnp.uint32),
+                "loss": carry["loss"] + jnp.sum(
+                    losses * participating.astype(jnp.float32)),
+                **{c: carry[c] + counts[c] for c in _STREAM_COUNTER_KEYS},
+            }
         return new, None
 
     acc, _ = jax.lax.scan(body, carry0, xs)
-
-    wsum = acc["wsum"].astype(jnp.float32)
-    safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
-    # reciprocal form, matching the slab participation branch — see
-    # federated_round
-    recip = jnp.float32(1.0) / safe_wsum
-    agg = {
-        p: (v.astype(jnp.float32) if packed else v) * recip
-        for p, v in acc["votes"].items()
-    }
-    b_vec = _round_b_vec(zspecs, cfg, state, round_index)
-    new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
-    new_dense_agg = jax.tree.map(lambda a: a * recip, acc["dense"])
-    skip = acc["num_participating"] < cfg.min_clients
-    new_scores = {
-        p: jnp.where(skip, state["scores"][p], new_enc[p])
-        for p in new_enc
-    }
-    new_dense = jax.tree.map(
-        lambda old, new: jnp.where(skip, old, new),
-        dict(state["dense"]), new_dense_agg,
-    )
-    cnt = acc["num_participating"]
-    safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
-    loss = acc["loss"] * (jnp.float32(1.0) / safe_cnt)
-    metrics = {
-        "loss": loss,
-        **realized_wire_metrics(_wire_metrics(zspecs, cfg, k, b_vec),
-                                acc["uplink_units"], k),
-        "cohort_size": float(k),
-        **{c: acc[c] for c in _STREAM_COUNTER_KEYS
-           if c != "uplink_units"},
-        "weight_sum": wsum,
-        "round_skipped": skip.astype(jnp.float32),
-    }
-    return {"scores": new_scores, "dense": new_dense,
-            **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
-                                  skip)}, metrics
+    with jax.named_scope(FED_AGGREGATE):
+        wsum = acc["wsum"].astype(jnp.float32)
+        safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
+        # reciprocal form, matching the slab participation branch — see
+        # federated_round
+        recip = jnp.float32(1.0) / safe_wsum
+        agg = {
+            p: (v.astype(jnp.float32) if packed else v) * recip
+            for p, v in acc["votes"].items()
+        }
+        b_vec = _round_b_vec(zspecs, cfg, state, round_index)
+        new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
+        new_dense_agg = jax.tree.map(lambda a: a * recip, acc["dense"])
+        skip = acc["num_participating"] < cfg.min_clients
+        new_scores = {
+            p: jnp.where(skip, state["scores"][p], new_enc[p])
+            for p in new_enc
+        }
+        new_dense = jax.tree.map(
+            lambda old, new: jnp.where(skip, old, new),
+            dict(state["dense"]), new_dense_agg,
+        )
+        cnt = acc["num_participating"]
+        safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
+        loss = acc["loss"] * (jnp.float32(1.0) / safe_cnt)
+        metrics = {
+            "loss": loss,
+            **realized_wire_metrics(_wire_metrics(zspecs, cfg, k, b_vec),
+                                    acc["uplink_units"], k),
+            "cohort_size": float(k),
+            **{c: acc[c] for c in _STREAM_COUNTER_KEYS
+               if c != "uplink_units"},
+            "weight_sum": wsum,
+            "round_skipped": skip.astype(jnp.float32),
+        }
+        return {"scores": new_scores, "dense": new_dense,
+                **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
+                                      skip)}, metrics
 
 
 def federated_round(
@@ -896,88 +907,88 @@ def federated_round(
         return local_update(zspecs, state, loss_fn, batches, w, cfg, opt)
 
     z_all, dense_all, losses = jax.vmap(one)(client_batches, words)
+    with jax.named_scope(FED_AGGREGATE):
+        if not participation:
+            # server aggregation: p(t+1) = mean_k z^(k), via the wire
+            # transport, re-encoded as the next broadcast (cfg.downlink's
+            # wire words)
+            b_vec = _round_b_vec(zspecs, cfg, state, round_index)
+            agg = _aggregate_stacked(zspecs, transport, packed, z_all)
+            new_scores = _encode_scores(zspecs, cfg, agg, key, round_index,
+                                        b_vec)
+            new_dense = jax.tree.map(lambda d: jnp.mean(d, axis=0), dense_all)
+            metrics = {"loss": jnp.mean(losses),
+                       **_wire_metrics(zspecs, cfg, k, b_vec),
+                       **_full_participation_metrics(k)}
+            return {"scores": new_scores, "dense": new_dense,
+                    **_schedule_state_out(zspecs, cfg, agg, state,
+                                          b_vec)}, metrics
 
-    if not participation:
-        # server aggregation: p(t+1) = mean_k z^(k), via the wire
-        # transport, re-encoded as the next broadcast (cfg.downlink's
-        # wire words)
+        # ---- partial participation: faults -> validation -> weighted mean
+        z_wire, codes, arrived, participating = _resolve_faults(
+            zspecs, packed, z_all, faults, round_index, ids)
+        w = (jnp.ones((k,), jnp.uint32) if weights is None
+             else jnp.asarray(weights).astype(jnp.uint32))
+        w_eff = w * participating.astype(jnp.uint32)
+        wsum = jnp.sum(w_eff, dtype=jnp.uint32).astype(jnp.float32)
+        safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
+        # RECIPROCAL form everywhere below, never `x / safe_wsum`: XLA
+        # strength-reduces the legacy path's divisions by a CONSTANT count
+        # (aggregate_stacked's `/ K`, jnp.mean, psum / axis_size) into a
+        # reciprocal multiply, and a runtime `x * (1/w)` reproduces that
+        # bit for bit at any K while a true division drifts by an ulp
+        # whenever the weight sum is not a power of two
+        recip = jnp.float32(1.0) / safe_wsum
+        if packed:
+            agg = {
+                p: transport.aggregate_stacked_packed_weighted(
+                    z_wire[p], zspecs.specs[p].n, w_eff
+                ).astype(jnp.float32) * recip
+                for p in z_wire
+            }
+        else:
+            agg = {
+                p: transport.aggregate_stacked_weighted(z, w_eff) * recip
+                for p, z in z_wire.items()
+            }
+        counters = _fault_counts(codes, arrived, participating)
         b_vec = _round_b_vec(zspecs, cfg, state, round_index)
-        agg = _aggregate_stacked(zspecs, transport, packed, z_all)
-        new_scores = _encode_scores(zspecs, cfg, agg, key, round_index,
-                                    b_vec)
-        new_dense = jax.tree.map(lambda d: jnp.mean(d, axis=0), dense_all)
-        metrics = {"loss": jnp.mean(losses),
-                   **_wire_metrics(zspecs, cfg, k, b_vec),
-                   **_full_participation_metrics(k)}
+        new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
+        w_f = w_eff.astype(jnp.float32)
+
+        def dense_mean(d):
+            wcol = w_f.reshape((k,) + (1,) * (d.ndim - 1))
+            return jnp.sum(d * wcol, axis=0) * recip
+
+        new_dense_agg = jax.tree.map(dense_mean, dense_all)
+        # skip-round: below min_clients the carried state passes through
+        # unchanged (averaging a near-empty cohort is sampling noise)
+        skip = counters["num_participating"] < cfg.min_clients
+        new_scores = {
+            p: jnp.where(skip, state["scores"][p], new_enc[p])
+            for p in new_enc
+        }
+        new_dense = jax.tree.map(
+            lambda old, new: jnp.where(skip, old, new),
+            dict(state["dense"]), new_dense_agg,
+        )
+        part_f = participating.astype(jnp.float32)
+        cnt = counters["num_participating"]
+        safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
+        loss = jnp.sum(losses * part_f) * (jnp.float32(1.0) / safe_cnt)
+        uplink_units = counters.pop("uplink_units")
+        metrics = {
+            "loss": loss,
+            **realized_wire_metrics(_wire_metrics(zspecs, cfg, k, b_vec),
+                                    uplink_units, k),
+            "cohort_size": float(k),
+            **counters,
+            "weight_sum": wsum,
+            "round_skipped": skip.astype(jnp.float32),
+        }
         return {"scores": new_scores, "dense": new_dense,
-                **_schedule_state_out(zspecs, cfg, agg, state,
-                                      b_vec)}, metrics
-
-    # ---- partial participation: faults -> validation -> weighted mean
-    z_wire, codes, arrived, participating = _resolve_faults(
-        zspecs, packed, z_all, faults, round_index, ids)
-    w = (jnp.ones((k,), jnp.uint32) if weights is None
-         else jnp.asarray(weights).astype(jnp.uint32))
-    w_eff = w * participating.astype(jnp.uint32)
-    wsum = jnp.sum(w_eff, dtype=jnp.uint32).astype(jnp.float32)
-    safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
-    # RECIPROCAL form everywhere below, never `x / safe_wsum`: XLA
-    # strength-reduces the legacy path's divisions by a CONSTANT count
-    # (aggregate_stacked's `/ K`, jnp.mean, psum / axis_size) into a
-    # reciprocal multiply, and a runtime `x * (1/w)` reproduces that
-    # bit for bit at any K while a true division drifts by an ulp
-    # whenever the weight sum is not a power of two
-    recip = jnp.float32(1.0) / safe_wsum
-    if packed:
-        agg = {
-            p: transport.aggregate_stacked_packed_weighted(
-                z_wire[p], zspecs.specs[p].n, w_eff
-            ).astype(jnp.float32) * recip
-            for p in z_wire
-        }
-    else:
-        agg = {
-            p: transport.aggregate_stacked_weighted(z, w_eff) * recip
-            for p, z in z_wire.items()
-        }
-    counters = _fault_counts(codes, arrived, participating)
-    b_vec = _round_b_vec(zspecs, cfg, state, round_index)
-    new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
-    w_f = w_eff.astype(jnp.float32)
-
-    def dense_mean(d):
-        wcol = w_f.reshape((k,) + (1,) * (d.ndim - 1))
-        return jnp.sum(d * wcol, axis=0) * recip
-
-    new_dense_agg = jax.tree.map(dense_mean, dense_all)
-    # skip-round: below min_clients the carried state passes through
-    # unchanged (averaging a near-empty cohort is sampling noise)
-    skip = counters["num_participating"] < cfg.min_clients
-    new_scores = {
-        p: jnp.where(skip, state["scores"][p], new_enc[p])
-        for p in new_enc
-    }
-    new_dense = jax.tree.map(
-        lambda old, new: jnp.where(skip, old, new),
-        dict(state["dense"]), new_dense_agg,
-    )
-    part_f = participating.astype(jnp.float32)
-    cnt = counters["num_participating"]
-    safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
-    loss = jnp.sum(losses * part_f) * (jnp.float32(1.0) / safe_cnt)
-    uplink_units = counters.pop("uplink_units")
-    metrics = {
-        "loss": loss,
-        **realized_wire_metrics(_wire_metrics(zspecs, cfg, k, b_vec),
-                                uplink_units, k),
-        "cohort_size": float(k),
-        **counters,
-        "weight_sum": wsum,
-        "round_skipped": skip.astype(jnp.float32),
-    }
-    return {"scores": new_scores, "dense": new_dense,
-            **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
-                                  skip)}, metrics
+                **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
+                                      skip)}, metrics
 
 
 def sharded_client_update(
@@ -1034,110 +1045,110 @@ def sharded_client_update(
         constraints=constraints, row_sharding=row_sharding,
     )
     nclients = axis_size(axis_names)
+    with jax.named_scope(FED_AGGREGATE):
+        if not participation:
+            if packed:
+                new_scores = {
+                    p: transport.aggregate_collective_packed(
+                        z, zspecs.specs[p].n, axis_names
+                    )
+                    for p, z in z_new.items()
+                }
+            else:
+                new_scores = {
+                    p: transport.aggregate_collective(z, axis_names)
+                    for p, z in z_new.items()
+                }
+            # re-encode the replicated aggregate as the next broadcast: the
+            # dither word comes from the replicated (key, round_index), so
+            # all shards produce the identical encoding — bit-equal to the
+            # vmap path (the schedule's b_vec is likewise a function of
+            # replicated values only)
+            b_vec = _round_b_vec(zspecs, cfg, state, round_index)
+            agg = new_scores
+            new_scores = _encode_scores(zspecs, cfg, agg, key,
+                                        round_index, b_vec)
+            # dense leaves stay on the f32 psum path: XLA:CPU's
+            # AllReducePromotion pass aborts on bf16 all-reduces (and f32
+            # is the numerically right accumulator anyway)
+            new_dense = jax.tree.map(
+                lambda d: (jax.lax.psum(d.astype(jnp.float32), axis_names)
+                           / nclients).astype(d.dtype),
+                dense_new,
+            )
+            loss = jax.lax.pmean(loss, axis_names)
+            # the mesh axis size, not cfg.num_clients, is the real K here
+            metrics = {"loss": loss,
+                       **_wire_metrics(zspecs, cfg, nclients, b_vec),
+                       **_full_participation_metrics(nclients)}
+            return {"scores": new_scores, "dense": new_dense,
+                    **_schedule_state_out(zspecs, cfg, agg, state,
+                                          b_vec)}, metrics
 
-    if not participation:
+        # ---- partial participation: every per-client quantity is a
+        # per-shard scalar; the psums realize the weighted server sum
+        z_wire, code, arrived, participating = _resolve_faults(
+            zspecs, packed, z_new, faults, round_index, my_id)
+        w = (jnp.uint32(1) if weight is None
+             else jnp.asarray(weight).astype(jnp.uint32))
+        w_eff = w * participating.astype(jnp.uint32)
+        wsum = jax.lax.psum(w_eff, tuple(axis_names)).astype(jnp.float32)
+        safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
+        # reciprocal form, matching the vmap path and the legacy path's
+        # constant divisions after XLA's strength reduction — see
+        # federated_round's participation branch
+        recip = jnp.float32(1.0) / safe_wsum
         if packed:
-            new_scores = {
-                p: transport.aggregate_collective_packed(
-                    z, zspecs.specs[p].n, axis_names
-                )
-                for p, z in z_new.items()
+            agg = {
+                p: transport.aggregate_collective_packed_weighted(
+                    z, zspecs.specs[p].n, w_eff, axis_names
+                ).astype(jnp.float32) * recip
+                for p, z in z_wire.items()
             }
         else:
-            new_scores = {
-                p: transport.aggregate_collective(z, axis_names)
-                for p, z in z_new.items()
+            agg = {
+                p: transport.aggregate_collective_weighted(
+                    z, w_eff, axis_names
+                ) * recip
+                for p, z in z_wire.items()
             }
-        # re-encode the replicated aggregate as the next broadcast: the
-        # dither word comes from the replicated (key, round_index), so
-        # all shards produce the identical encoding — bit-equal to the
-        # vmap path (the schedule's b_vec is likewise a function of
-        # replicated values only)
         b_vec = _round_b_vec(zspecs, cfg, state, round_index)
-        agg = new_scores
-        new_scores = _encode_scores(zspecs, cfg, agg, key,
-                                    round_index, b_vec)
-        # dense leaves stay on the f32 psum path: XLA:CPU's
-        # AllReducePromotion pass aborts on bf16 all-reduces (and f32
-        # is the numerically right accumulator anyway)
-        new_dense = jax.tree.map(
-            lambda d: (jax.lax.psum(d.astype(jnp.float32), axis_names)
-                       / nclients).astype(d.dtype),
+        new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
+        counters = {
+            k: jax.lax.psum(v, tuple(axis_names))
+            for k, v in _fault_counts(code, arrived, participating).items()
+        }
+        w_f = w_eff.astype(jnp.float32)
+        new_dense_agg = jax.tree.map(
+            lambda d: (jax.lax.psum(d.astype(jnp.float32) * w_f, axis_names)
+                       * recip).astype(d.dtype),
             dense_new,
         )
-        loss = jax.lax.pmean(loss, axis_names)
-        # the mesh axis size, not cfg.num_clients, is the real K here
-        metrics = {"loss": loss,
-                   **_wire_metrics(zspecs, cfg, nclients, b_vec),
-                   **_full_participation_metrics(nclients)}
+        skip = counters["num_participating"] < cfg.min_clients
+        new_scores = {
+            p: jnp.where(skip, state["scores"][p], new_enc[p])
+            for p in new_enc
+        }
+        new_dense = jax.tree.map(
+            lambda old, new: jnp.where(skip, old, new),
+            dict(state["dense"]), new_dense_agg,
+        )
+        cnt = counters["num_participating"]
+        safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
+        loss = jax.lax.psum(
+            loss * participating.astype(jnp.float32), tuple(axis_names)
+        ) * (jnp.float32(1.0) / safe_cnt)
+        uplink_units = counters.pop("uplink_units")
+        metrics = {
+            "loss": loss,
+            **realized_wire_metrics(
+                _wire_metrics(zspecs, cfg, nclients, b_vec),
+                uplink_units, nclients),
+            "cohort_size": float(nclients),
+            **counters,
+            "weight_sum": wsum,
+            "round_skipped": skip.astype(jnp.float32),
+        }
         return {"scores": new_scores, "dense": new_dense,
-                **_schedule_state_out(zspecs, cfg, agg, state,
-                                      b_vec)}, metrics
-
-    # ---- partial participation: every per-client quantity is a
-    # per-shard scalar; the psums realize the weighted server sum
-    z_wire, code, arrived, participating = _resolve_faults(
-        zspecs, packed, z_new, faults, round_index, my_id)
-    w = (jnp.uint32(1) if weight is None
-         else jnp.asarray(weight).astype(jnp.uint32))
-    w_eff = w * participating.astype(jnp.uint32)
-    wsum = jax.lax.psum(w_eff, tuple(axis_names)).astype(jnp.float32)
-    safe_wsum = jnp.where(wsum > 0, wsum, jnp.float32(1))
-    # reciprocal form, matching the vmap driver and the legacy path's
-    # constant divisions after XLA's strength reduction — see
-    # federated_round's participation branch
-    recip = jnp.float32(1.0) / safe_wsum
-    if packed:
-        agg = {
-            p: transport.aggregate_collective_packed_weighted(
-                z, zspecs.specs[p].n, w_eff, axis_names
-            ).astype(jnp.float32) * recip
-            for p, z in z_wire.items()
-        }
-    else:
-        agg = {
-            p: transport.aggregate_collective_weighted(
-                z, w_eff, axis_names
-            ) * recip
-            for p, z in z_wire.items()
-        }
-    b_vec = _round_b_vec(zspecs, cfg, state, round_index)
-    new_enc = _encode_scores(zspecs, cfg, agg, key, round_index, b_vec)
-    counters = {
-        k: jax.lax.psum(v, tuple(axis_names))
-        for k, v in _fault_counts(code, arrived, participating).items()
-    }
-    w_f = w_eff.astype(jnp.float32)
-    new_dense_agg = jax.tree.map(
-        lambda d: (jax.lax.psum(d.astype(jnp.float32) * w_f, axis_names)
-                   * recip).astype(d.dtype),
-        dense_new,
-    )
-    skip = counters["num_participating"] < cfg.min_clients
-    new_scores = {
-        p: jnp.where(skip, state["scores"][p], new_enc[p])
-        for p in new_enc
-    }
-    new_dense = jax.tree.map(
-        lambda old, new: jnp.where(skip, old, new),
-        dict(state["dense"]), new_dense_agg,
-    )
-    cnt = counters["num_participating"]
-    safe_cnt = jnp.where(cnt > 0, cnt, jnp.float32(1))
-    loss = jax.lax.psum(
-        loss * participating.astype(jnp.float32), tuple(axis_names)
-    ) * (jnp.float32(1.0) / safe_cnt)
-    uplink_units = counters.pop("uplink_units")
-    metrics = {
-        "loss": loss,
-        **realized_wire_metrics(
-            _wire_metrics(zspecs, cfg, nclients, b_vec),
-            uplink_units, nclients),
-        "cohort_size": float(nclients),
-        **counters,
-        "weight_sum": wsum,
-        "round_skipped": skip.astype(jnp.float32),
-    }
-    return {"scores": new_scores, "dense": new_dense,
-            **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
-                                  skip)}, metrics
+                **_schedule_state_out(zspecs, cfg, agg, state, b_vec,
+                                      skip)}, metrics

@@ -89,6 +89,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..tracing import QZ_LAYOUT
 from .qspec import (
     QSpec,
     edge_sum,
@@ -182,11 +183,13 @@ def _insert_padding(spec: QSpec, flat_moved):
     ).reshape(-1)
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _unmove(spec: QSpec, flat_moved):
     w = flat_moved.reshape(spec.moved_shape)
     return jnp.moveaxis(w, 0, spec.major_axis)
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _move(spec: QSpec, w):
     return jnp.moveaxis(w, spec.major_axis, 0).reshape(-1)
 
@@ -208,6 +211,7 @@ def _insert_padding_batched(spec: QSpec, flat_moved):
     ).reshape(k, spec.m_pad)
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _unmove_batched(spec: QSpec, flat_moved):
     """(K, m) moved flat order -> (K, *spec.shape)."""
     k = flat_moved.shape[0]
@@ -215,6 +219,7 @@ def _unmove_batched(spec: QSpec, flat_moved):
     return jnp.moveaxis(w, 1, spec.major_axis + 1)
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _move_batched(spec: QSpec, w):
     """(K, *spec.shape) -> (K, m) moved flat order."""
     return jnp.moveaxis(w, spec.major_axis + 1, 1).reshape(w.shape[0], -1)

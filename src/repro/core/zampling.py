@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..tracing import FED_MODEL
 from .qspec import QSpec, make_qspec
 from .sampling import (
     as_word,
@@ -374,8 +375,12 @@ class MaskProgram:
         tmpl = dict(_flatten(self.zspecs.template))
         leaves = {}
         for path, spec in self.zspecs.specs.items():
+            # the operand's clip is the model's; no scope may enclose
+            # the kernel call (repro.tracing)
+            with jax.named_scope(FED_MODEL):
+                p = clip_probs(scores[path])
             w = ops.sample_reconstruct(
-                spec, clip_probs(scores[path]), step,
+                spec, p, step,
                 dtype=tmpl[path].dtype, chunks=self.zspecs.config.chunks,
                 impl=self.impl, row_sharding=row_sharding,
             )

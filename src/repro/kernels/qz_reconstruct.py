@@ -81,6 +81,16 @@ vmap-batched, and the shard_map federated path — both sides regenerate
 the identical mask bits from ``(seed, tensor_id, step, coord)``.
 tests/test_tpu_compile.py compiles the main-path kernels for a
 described TPU v5e at the paper's MNIST-FC widths.
+
+Trace names: the operand and result re-layouts around the kernels
+(``_grid_rows_in`` / ``_grid_rows_out`` here, and ``core.reconstruct``'s
+moves into and out of the sharding-major row order) run under the
+``qz.layout`` named scope (``repro.tracing``), which closes before
+each ``pallas_call`` is traced.  No scope encloses a ``pallas_call``: the
+kernels' HLO instruction names come from the name stack they are
+traced under (``jvp(...)`` / ``transpose(jvp(...))`` of the
+``custom_vjp`` in ``kernels.ops``), and the trace readers find them by
+those names.
 """
 
 from __future__ import annotations
@@ -97,6 +107,7 @@ from ..core.hashrng import bernoulli_u32
 from ..core.qspec import QSpec, edge_index, edge_value, row_hashes
 from ..core.sampling import mask_u32, quant_threshold_u24
 from ..core.transpose_plan import build_block_plan
+from ..tracing import QZ_LAYOUT
 
 DEFAULT_BM = 256
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -166,6 +177,7 @@ def _bbwd_kernel(g_ref, gz_ref, *, spec: QSpec, bm: int):
     gz_ref[...] += acc
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _grid_rows_in(spec: QSpec, grad_W, bm: int):
     """(K, m) cotangents -> the (K, m_grid) per-window padded layout."""
     nclients = grad_W.shape[0]
@@ -179,6 +191,7 @@ def _grid_rows_in(spec: QSpec, grad_W, bm: int):
     return g.reshape(nclients, m_grid)
 
 
+@jax.named_scope(QZ_LAYOUT)
 def _grid_rows_out(spec: QSpec, out, bm: int):
     """(K, m_grid) kernel rows -> (K, m) (drop the per-window padding)."""
     nw, bpw, _ = _grid_dims(spec, bm)

@@ -113,10 +113,10 @@ def main():
         key, sub = jax.random.split(key)
         t0 = time.time()
         state, met = round_fn(state, batch, sub)
+        loss = float(met["loss"])  # waits for the round
         dt = time.time() - t0
-        history.append(float(met["loss"]))
-        print(f"[round {r:3d}] loss={met['loss']:.4f}  ({dt:.1f}s)",
-              flush=True)
+        history.append(loss)
+        print(f"[round {r:3d}] loss={loss:.4f}  ({dt:.1f}s)", flush=True)
 
     save_checkpoint(os.path.join(args.out, "ckpt"), state,
                     meta={"arch": cfg.name, "q_seed": 0,

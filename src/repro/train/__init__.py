@@ -1,3 +1,4 @@
+from .. import tracing
 from .fit import federated_fit, sharded_client_fit, streamed_federated_fit
 from .local import LocalTrainConfig, evaluate, train_local_zampling
 from .steps import TrainState, make_train_step, make_zampling_train_step
@@ -7,3 +8,6 @@ __all__ = [
     "TrainState", "make_train_step", "make_zampling_train_step",
     "federated_fit", "sharded_client_fit", "streamed_federated_fit",
 ]
+
+# count compiles from the first round traced on (repro.tracing)
+tracing.install()

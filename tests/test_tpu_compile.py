@@ -9,13 +9,23 @@ serve widths, and asserts the kernel is in the compiled program
 compiler refuses — unsupported casts, lane-crossing reshapes,
 misaligned blocks — fails here.
 
+``test_round_trace_names`` compiles a whole federated round the same
+way and guards the names the benchmark's trace readers match: the two
+kernels' HLO instruction names (``bench/metrics/reconstruct_roofline.py``
+and ``bwd_plan_roofline.py``), which no named scope may rename, and the
+program's scopes on the ops around them.
+
 The topology is described only inside the module fixture, never at
 import or collection: one process at a time may load the TPU compiler
 library, so under parallel test workers only the worker that runs this
 file loads it, and every worker still collects the same tests.
 """
 
+import importlib.util
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +36,8 @@ from repro.core.qspec import make_qspec
 from repro.kernels import qz_decode, qz_reconstruct
 
 K = 10
+ROOT = Path(__file__).resolve().parents[1]
+OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @pytest.fixture(scope="module")
@@ -87,3 +99,70 @@ def test_sample_matmul(chip):
     _compile(chip, lambda p, s, X: qz_decode.qz_sample_matmul(
         spec, p, s, X, d_in=256, d_out=512, qbits=8, interpret=False),
         ((spec.n,), jnp.uint8), ((), jnp.uint32), ((4, 256), jnp.float32))
+
+
+def _reader_pattern(name):
+    """``PATTERN`` of a benchmark reader, read from its own file."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name, ROOT / "bench" / "metrics" / (name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.PATTERN
+
+
+def test_round_trace_names(chip, monkeypatch):
+    """The federated round at reduced widths (784-20-20-10, K=2, E=2),
+    as the benchmark's k10 cell configures it: one fused-forward and one
+    plan-backward kernel per zampled tensor, named as the readers
+    expect and under no scope; every program scope on some other op."""
+    from repro import tracing
+    from repro.core import FederatedConfig, ZamplingConfig, build_specs
+    from repro.kernels import ops
+    from repro.models.mlp import init_mlp_params, mlp_loss
+    from repro.train import federated_fit
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(ops, "_DEFAULT_IMPL", "pallas")
+    dims, k, e, b = (784, 20, 20, 10), 2, 2, 8
+    template = jax.eval_shape(lambda key: init_mlp_params(key, dims),
+                              jax.random.PRNGKey(0))
+    zspecs = build_specs(template, ZamplingConfig(
+        compression=32, d=10, window=128, seed=0, min_size=128))
+    fcfg = FederatedConfig(num_clients=k, local_steps=e, local_lr=0.5,
+                           aggregate="psum_u32", downlink="u8")
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    leaves = {"/".join(str(getattr(q, "key", q)) for q in path): leaf
+              for path, leaf in jax.tree_util.tree_flatten_with_path(
+                  template)[0]}
+    state = {"scores": {p: on_chip((s.n,), jnp.uint8)
+                        for p, s in zspecs.specs.items()},
+             "dense": {p: on_chip(leaves[p].shape, leaves[p].dtype)
+                       for p in zspecs.dense_paths}}
+    batches = {"x": on_chip((1, k, e, b, dims[0]), jnp.float32),
+               "y": on_chip((1, k, e, b), jnp.int32)}
+
+    def fit(s, bt, key):
+        return federated_fit(zspecs, s, mlp_loss, bt, key, fcfg)
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(fit).lower(state, batches,
+                                  on_chip((2,), jnp.uint32)).compile(
+        ).as_text()
+    lines = [re.sub(r"^ROOT ", "", ln.strip()) for ln in text.splitlines()]
+    tensors = len(zspecs.specs)
+    for reader in ("reconstruct_roofline", "bwd_plan_roofline"):
+        rx = re.compile(_reader_pattern(reader))
+        kernels = [ln for ln in lines if rx.search(ln)]
+        assert len(kernels) == tensors, (reader, kernels)
+        for ln in kernels:
+            op_name = OP_NAME.search(ln).group(1)
+            assert op_name.endswith("pallas_call"), op_name
+            assert not any(sc in op_name for sc in tracing.SCOPES), op_name
+    found = {sc for ln in lines for m in [OP_NAME.search(ln)] if m
+             for sc in tracing.SCOPES if sc in m.group(1)}
+    assert found == set(tracing.SCOPES)
