@@ -53,9 +53,15 @@ across modes, and against the scatter oracle, the contract is
 
 ``build_block_plan(spec, bm)`` re-bins the same edges by the Pallas
 backward's row-block grid (``kernels.qz_reconstruct``): cell (window
-i, block j, coordinate c) holds the edges whose source row falls in
-rows [j·bm, (j+1)·bm) of window i, rows stored block-relative so the
-kernel's gather is an in-block one-hot contraction.
+i, block j, sub-block b, coordinate c) holds the edges whose source
+row falls in rows [j·bm + b·sub, j·bm + (b+1)·sub) of window i, rows
+stored sub-block-relative so the kernel's gather is an in-sub-block
+one-hot contraction.  The ``bm``-row block fixes the summation order
+(the kernel adds a block's sub-blocks into one accumulator, sub-block
+0 first); ``sub`` (``sub_block_rows``) only sizes each one-hot.  In
+canonical order a block's edges, sub-block by sub-block, are exactly
+its ascending-row sequence; in slot order they are that sequence
+grouped by sub-block.
 
 Path gating: ``resolve_bwd_path()`` decides scatter vs plan at TRACE
 time.  The ``REPRO_BWD_PLAN`` env var overrides the process default
@@ -196,18 +202,33 @@ class TransposePlan:
 class BlockPlan:
     """Transpose plan re-binned to the Pallas (window, row-block) grid.
 
-    ``rows[i, j, c, e]`` is BLOCK-relative (in [0, bm)): the source row
-    of edge ``e`` into in-window coordinate ``c``, among the rows
-    [j·bm, (j+1)·bm) of window i.  ``deg`` is the max in-degree over
-    all (window, block, coordinate) cells.
+    Each ``bm``-row block j of window i splits into ``nsub`` sub-blocks
+    of ``sub`` rows.  ``rows[i, j, b, e, c]`` is SUB-BLOCK-relative (in
+    [0, sub)): the source row of edge ``e`` into in-window coordinate
+    ``c``, among the rows [j·bm + b·sub, j·bm + (b+1)·sub) of window i.
+    Slot-major, so one grid step reads its ``nsub`` (deg, window)
+    sub-plans with the coordinates on the lanes.  ``deg`` is the max
+    in-degree over all (window, block, sub-block, coordinate) cells;
+    padding entries point at row 0 with value 0.
     """
 
     order: str
     bm: int
     bpw: int
+    sub: int
     deg: int
-    rows: np.ndarray  # (num_windows, bpw, window, deg) int32
-    vals: np.ndarray  # (num_windows, bpw, window, deg) f32
+    rows: np.ndarray  # (num_windows, bpw, nsub, deg, window) int32
+    vals: np.ndarray  # (num_windows, bpw, nsub, deg, window) f32
+
+    @property
+    def nsub(self) -> int:
+        return self.bm // self.sub
+
+    @property
+    def onehot_elems(self) -> int:
+        """One-hot elements a grid block builds: ``nsub·deg·sub·window``
+        (per client row of the gather, the VPU work the plan costs)."""
+        return self.nsub * self.deg * self.sub * self.rows.shape[-1]
 
 
 def _edges(spec: QSpec, order: str):
@@ -287,20 +308,52 @@ def build_transpose_plan(spec: QSpec,
     )
 
 
-@functools.lru_cache(maxsize=32)
+# Rows of one sub-block: the one-hot each plan slot of the backward's
+# gather builds is (SUB_ROWS, window).  Its time follows the one-hot's
+# size: of 128 and 64 rows, 64 ran faster on a TPU v5e (PERF.md).
+SUB_ROWS = 64
+
+
+def sub_block_rows(spec: QSpec, bm: int) -> int:
+    """Rows of one sub-block of the backward's ``bm``-row block.
+
+    ``SUB_ROWS`` where the block's rows tile by it and the window fills
+    more than one sub-block (each one-hot is then a fraction of the
+    block's, with a far shallower plan); else the whole block (``bm``
+    of at most ``SUB_ROWS`` rows, or a window of at most ``SUB_ROWS``
+    rows, whose other sub-blocks would hold no edge).
+    """
+    if bm > SUB_ROWS and bm % SUB_ROWS == 0 \
+            and spec.rows_per_window > SUB_ROWS:
+        return SUB_ROWS
+    return bm
+
+
 def build_block_plan(spec: QSpec, bm: int,
                      order: str = "canonical") -> BlockPlan:
-    """Transpose plan binned per (window, bm-row-block, coordinate)."""
+    """Transpose plan binned per (window, bm-row block, sub-block,
+    coordinate), sub-blocks of ``sub_block_rows(spec, bm)`` rows."""
+    return _bin_block_plan(spec, bm, sub_block_rows(spec, bm), order)
+
+
+@functools.lru_cache(maxsize=32)
+def _bin_block_plan(spec: QSpec, bm: int, sub: int,
+                   order: str = "canonical") -> BlockPlan:
+    """``build_block_plan`` with sub-blocks of ``sub`` rows (``sub``
+    divides ``bm``; ``sub = bm`` is one sub-block per block)."""
+    if bm % sub:
+        raise ValueError(f"sub-block of {sub} rows does not divide bm={bm}")
     c, r, v = _edges(spec, order)
-    bpw = max(1, -(-spec.rows_per_window // bm))
-    blk, rblk = r // bm, (r % bm).astype(np.int64)
-    w, cw = c // spec.window, c % spec.window
-    key = ((w * bpw + blk) * spec.window + cw).astype(np.int64)
+    nw, win = spec.num_windows, spec.window
+    bpw, nsub = max(1, -(-spec.rows_per_window // bm)), bm // sub
+    # sub-block index within the window (block j, sub-block b -> j·nsub + b)
+    key = ((c // win) * (bpw * nsub) + r // sub) * win + c % win
     rows_pad, vals_pad, _, deg = _pack(
-        key, rblk, v, spec.num_windows * bpw * spec.window
-    )
+        key.astype(np.int64), (r % sub).astype(np.int64), v,
+        nw * bpw * nsub * win)
+    shape = (nw, bpw, nsub, win, deg)
     return BlockPlan(
-        order=order, bm=bm, bpw=bpw, deg=deg,
-        rows=rows_pad.reshape(spec.num_windows, bpw, spec.window, deg),
-        vals=vals_pad.reshape(spec.num_windows, bpw, spec.window, deg),
+        order=order, bm=bm, bpw=bpw, sub=sub, deg=deg,
+        rows=np.ascontiguousarray(np.swapaxes(rows_pad.reshape(shape), 3, 4)),
+        vals=np.ascontiguousarray(np.swapaxes(vals_pad.reshape(shape), 3, 4)),
     )

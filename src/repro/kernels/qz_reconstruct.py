@@ -25,14 +25,23 @@ Backward ``grad_z = Q^T grad_w`` (``kernels.ops`` dispatches via
 
  - PLAN (default, ``qz_reconstruct_batched_bwd_plan``): the cached
    per-spec transpose plan re-binned to this grid (``build_block_plan``)
-   — cell (window i, row-block j, coordinate c) carries the
-   degree-padded incoming edges whose source row lies in rows
-   [j·bm, (j+1)·bm) of window i, rows stored BLOCK-relative.  For each
-   of the ``deg`` plan slots the gather is ``g (K, bm) @ onehot (bm,
-   window)``, scaled by the slot's values.  Blocks accumulate over the
-   ``j`` grid dimension, so the Pallas plan path is its OWN ordering
-   mode: deterministic per (spec, bm), ``allclose`` vs the ref plan /
-   scatter paths.
+   and, inside each ``bm``-row block, to sub-blocks of ``sub`` rows
+   (``sub_block_rows``: ``SUB_ROWS`` = 64 where the block's rows tile
+   by it, else ``bm``) — cell (window i, block j, sub-block b,
+   coordinate c) carries the degree-padded incoming edges whose source
+   row lies in rows [j·bm + b·sub, j·bm + (b+1)·sub) of window i, rows
+   stored sub-block-relative.  For each sub-block, then each of its
+   ``deg`` plan slots, the gather is ``g[:, b·sub:(b+1)·sub] (K, sub)
+   @ onehot (sub, window)``, scaled by the slot's values, all into one
+   accumulator that the block adds to its window's grad-z once.  Each
+   one-hot is ``sub`` rows high with a plan far shallower than the
+   block's, and the sums are unchanged: in canonical order each
+   coordinate adds its edges in ascending source row within a
+   ``bm``-row block (padding adds an exact 0), then the blocks in
+   order — the order the benchmark's float32 reference fixes
+   (``transpose_block`` 256).  Across paths the contract stays
+   ``allclose`` vs the ref plan / scatter paths, which sum in other
+   orders; in slot order the block's edges are grouped by sub-block.
  - SCATTER (oracle, ``qz_reconstruct_batched_bwd``): per edge slot,
    ``(g · vals) (K, bm) @ onehot (bm, window)``.
 
@@ -269,44 +278,41 @@ def qz_reconstruct_bwd(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,
 # cached block plan (see module docstring and core.transpose_plan).
 # ---------------------------------------------------------------------------
 
-def _plan_operands(spec: QSpec, bm: int, order: str):
-    """Block-plan slabs, slot-major ((nw, bpw, deg, window): the window's
-    coordinates on the lanes), as jnp constants + their BlockSpec."""
-    plan = build_block_plan(spec, bm, order)
-    bspec = pl.BlockSpec((1, 1, plan.deg, spec.window),
-                         lambda i, j: (i, j, 0, 0))
-    return (jnp.asarray(np.swapaxes(plan.rows, 2, 3)),
-            jnp.asarray(np.swapaxes(plan.vals, 2, 3)), plan.deg, bspec)
-
-
 def _bbwd_plan_kernel(g_ref, rows_ref, vals_ref, gz_ref, *, spec: QSpec,
-                      bm: int, deg: int):
+                      sub: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         gz_ref[...] = jnp.zeros_like(gz_ref)
 
     g = g_ref[...].astype(jnp.float32)  # (K, bm)
-    rows = rows_ref[0, 0]  # (deg, window) block-relative source rows
+    rows = rows_ref[0, 0]  # (nsub, deg, window) sub-block-relative rows
     vals = vals_ref[0, 0]
-    src = _iota((bm, spec.window), 0)
+    nsub, deg, _ = rows.shape
+    src = _iota((sub, spec.window), 0)
+    one, zero = jnp.float32(1.0), jnp.float32(0.0)
+    # one chain per block: sub-block 0's slots, then sub-block 1's, ...
+    # — each coordinate's edges in the block plan's order (padding adds
+    # an exact 0), the same sums as one block-high one-hot per slot
     acc = jnp.zeros(gz_ref.shape, jnp.float32)
-    for e in range(deg):
-        onehot = (src == rows[e:e + 1, :]).astype(jnp.float32)
-        acc = acc + vals[e:e + 1, :] * _dot(g, onehot, HIGHEST)
+    for b in range(nsub):
+        g_b = g[:, b * sub:(b + 1) * sub]
+        for e in range(deg):
+            onehot = jnp.where(src == rows[b, e:e + 1, :], one, zero)
+            acc = acc + vals[b, e:e + 1, :] * _dot(g_b, onehot, HIGHEST)
     gz_ref[...] += acc
 
 
-def qz_reconstruct_batched_bwd_plan(spec: QSpec, grad_W, *,
-                                    bm: int = DEFAULT_BM,
-                                    interpret: bool = False,
-                                    order: str = "canonical"):
-    """Plan-driven batched backward: grad_W (K, m) -> grad_Z (K, n)."""
-    nclients = grad_W.shape[0]
+def _bwd_plan_call(spec: QSpec, grad_W, plan, *, interpret: bool):
+    """The plan backward's ``pallas_call`` over a built ``BlockPlan``:
+    grid (num_windows, blocks_per_window) of ``plan.bm`` rows, each
+    step reading its (nsub, deg, window) sub-plans."""
+    nclients, bm = grad_W.shape[0], plan.bm
     nw, bpw, _ = _grid_dims(spec, bm)
-    rows, vals, deg, bspec = _plan_operands(spec, bm, order)
+    bspec = pl.BlockSpec((1, 1) + plan.rows.shape[2:],
+                         lambda i, j: (i, j, 0, 0, 0))
     out_spec, out_shape = _gz_out(spec, nclients)
     return pl.pallas_call(
-        functools.partial(_bbwd_plan_kernel, spec=spec, bm=bm, deg=deg),
+        functools.partial(_bbwd_plan_kernel, spec=spec, sub=plan.sub),
         grid=(nw, bpw),
         in_specs=[
             pl.BlockSpec((nclients, bm), lambda i, j: (0, i * bpw + j)),
@@ -315,7 +321,17 @@ def qz_reconstruct_batched_bwd_plan(spec: QSpec, grad_W, *,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(_grid_rows_in(spec, grad_W, bm), rows, vals)
+    )(_grid_rows_in(spec, grad_W, bm), jnp.asarray(plan.rows),
+      jnp.asarray(plan.vals))
+
+
+def qz_reconstruct_batched_bwd_plan(spec: QSpec, grad_W, *,
+                                    bm: int = DEFAULT_BM,
+                                    interpret: bool = False,
+                                    order: str = "canonical"):
+    """Plan-driven batched backward: grad_W (K, m) -> grad_Z (K, n)."""
+    return _bwd_plan_call(spec, grad_W, build_block_plan(spec, bm, order),
+                          interpret=interpret)
 
 
 def qz_reconstruct_bwd_plan(spec: QSpec, grad_w, *, bm: int = DEFAULT_BM,

@@ -33,6 +33,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.qspec import make_qspec
+from repro.core.transpose_plan import build_block_plan
 from repro.kernels import qz_decode, qz_reconstruct
 
 K = 10
@@ -63,6 +64,7 @@ def _compile(chip, fn, *shapes):
             for shape, dtype in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def test_layer0_widths(layer0):
@@ -77,6 +79,19 @@ def test_reconstruct_batched_fwd(chip, layer0):
 def test_reconstruct_batched_bwd_plan(chip, layer0):
     _compile(chip, lambda G: qz_reconstruct.qz_reconstruct_batched_bwd_plan(
         layer0, G, interpret=False), ((K, layer0.m), jnp.float32))
+
+
+def test_reconstruct_batched_bwd_plan_cell_widths(chip):
+    # the benchmark cell's layer0/kernel: compression 32, d=10, K=10,
+    # each 256-row block gathered in sub-blocks
+    spec = make_qspec(1, (784, 300), 784, compression=32, d=10, window=128,
+                      seed=0)
+    assert build_block_plan(spec, 256).nsub > 1
+    text = _compile(
+        chip, lambda G: qz_reconstruct.qz_reconstruct_batched_bwd_plan(
+            spec, G, interpret=False), ((K, spec.m), jnp.float32))
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
 
 
 @pytest.mark.parametrize("qbits,dtype", [(None, jnp.float32),

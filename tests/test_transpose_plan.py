@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.core.qspec import make_qspec
+from repro.core.qspec import make_qspec, padded_row_valid
 from repro.core.reconstruct import (
     grad_z_batched_ref,
     grad_z_plan_batched_ref,
@@ -26,10 +26,15 @@ from repro.core.reconstruct import (
     materialize_q,
 )
 from repro.core.transpose_plan import (
+    _bin_block_plan,
+    _host_eval,
     build_block_plan,
     build_transpose_plan,
     resolve_bwd_path,
+    SUB_ROWS,
+    row_plan,
     set_default_bwd_path,
+    sub_block_rows,
 )
 from repro.kernels import ops
 from repro.kernels.qz_reconstruct import (
@@ -153,11 +158,162 @@ def test_block_plan_geometry():
     spec = _mk((900, 30), 16.0, 8, 128, {})
     bp = build_block_plan(spec, 64)
     assert bp.bpw == -(-spec.rows_per_window // 64)
-    assert bp.rows.shape == (spec.num_windows, bp.bpw, spec.window, bp.deg)
+    assert (bp.sub, bp.nsub) == (64, 1)  # under a lane tile: one sub-block
+    assert bp.rows.shape == (spec.num_windows, bp.bpw, 1, bp.deg,
+                             spec.window)
     assert bp.rows.max() < 64  # block-relative
     flat = build_transpose_plan(spec)
     # re-binning preserves the edge multiset per coordinate
     assert (bp.vals != 0).sum() == flat.n_edges
+
+
+# the benchmark cell's layer0/kernel (784x300, compression 32, d=10,
+# window 128, tensor 1, seed 0), as build_specs makes it
+def _cell_layer0():
+    return make_qspec(1, (784, 300), 784, compression=32, d=10,
+                      window=128, seed=0)
+
+
+def _cell_edges(plan):
+    """Each (window, block, coordinate) cell's live edges in summation
+    order: rows block-relative, as (cells, L) rows / vals, live first."""
+    nw, bpw, nsub, deg, win = plan.rows.shape
+    rows = plan.rows + (np.arange(nsub) * plan.sub)[:, None, None]
+    rows = np.moveaxis(rows, 4, 2).reshape(nw * bpw * win, nsub * deg)
+    vals = np.moveaxis(plan.vals, 4, 2).reshape(rows.shape)
+    live = vals != 0
+    perm = np.argsort(~live, axis=1, kind="stable")
+    rows = np.where(np.take_along_axis(live, perm, 1),
+                    np.take_along_axis(rows, perm, 1), 0)
+    return rows, np.take_along_axis(vals, perm, 1), live.sum(1)
+
+
+@pytest.mark.parametrize("order", ["canonical", "slot"])
+@pytest.mark.parametrize("which", ["rpw_not_256", "rpw_under_256",
+                                   "cell_layer0"])
+def test_block_plan_sub_blocks_keep_edge_order(which, order):
+    """SUB_ROWS-row sub-blocks, concatenated in sub-block order, give each
+    (window, 256-row block, coordinate) the 256-row plan's edges: the
+    same sequence in canonical order (ascending row), the sequence
+    grouped by sub-block in slot order."""
+    spec = {"rpw_not_256": lambda: _mk((900, 30), 16.0, 8, 128, {}),
+            "rpw_under_256": lambda: _mk((64, 96), 6.0, 4, 32, {}),
+            "cell_layer0": _cell_layer0}[which]()
+    assert spec.rows_per_window % 256 != 0
+    assert (spec.rows_per_window < 256) == (which == "rpw_under_256")
+    assert sub_block_rows(spec, 256) == SUB_ROWS
+    sub, whole = build_block_plan(spec, 256, order), _bin_block_plan(
+        spec, 256, 256, order)
+    nsub = 256 // SUB_ROWS
+    assert (sub.sub, sub.nsub, whole.nsub) == (SUB_ROWS, nsub, 1)
+    assert sub.rows.shape == (spec.num_windows, whole.bpw, nsub, sub.deg,
+                              spec.window)
+    assert sub.rows.max() < SUB_ROWS and sub.deg < whole.deg
+    rows_s, vals_s, n_s = _cell_edges(sub)
+    rows_w, vals_w, n_w = _cell_edges(whole)
+    np.testing.assert_array_equal(n_s, n_w)
+    width = int(n_w.max())
+    rows_s, vals_s = rows_s[:, :width], vals_s[:, :width]
+    rows_w, vals_w = rows_w[:, :width], vals_w[:, :width]
+    # the 256-row sequence, grouped by sub-block (padding stays last)
+    key = np.where(np.arange(width) < n_w[:, None], rows_w // SUB_ROWS,
+                   nsub)
+    grp = np.argsort(key, axis=1, kind="stable")
+    grouped_r = np.take_along_axis(rows_w, grp, 1)
+    grouped_v = np.take_along_axis(vals_w, grp, 1)
+    if order == "canonical":  # already ascending rows: grouping is a no-op
+        np.testing.assert_array_equal(grouped_r, rows_w)
+        np.testing.assert_array_equal(grouped_v, vals_w)
+    else:
+        assert (grouped_r != rows_w).any()
+    np.testing.assert_array_equal(rows_s, grouped_r)
+    np.testing.assert_array_equal(vals_s, grouped_v)
+    if which == "cell_layer0":
+        assert (SUB_ROWS, whole.deg, sub.deg) == (64, 43, 18)
+        assert whole.onehot_elems == 43 * 256 * 128
+        assert sub.onehot_elems == 4 * 18 * 64 * 128
+
+
+def test_sub_block_rows_rule():
+    big = _mk((900, 30), 16.0, 8, 128, {})  # 1,929 rows per window
+    assert SUB_ROWS == 64
+    assert sub_block_rows(big, 256) == 64
+    assert sub_block_rows(big, 512) == 64
+    assert sub_block_rows(big, 128) == 64
+    assert sub_block_rows(big, 64) == 64  # a block of one sub-block
+    assert sub_block_rows(big, 32) == 32
+    small = _mk((64, 96), 2.0, 4, 32, {})  # one sub-block per window
+    assert small.rows_per_window <= 64
+    assert sub_block_rows(small, 256) == 256
+    assert build_block_plan(small, 256).nsub == 1
+
+
+def _chain_oracle(spec, G, bm=256):
+    """float32 numpy ``Qᵀ g``: each coordinate adds its incoming edges
+    ``val·g`` one at a time, ascending (source row, slot) within each
+    ``bm``-row block of its window, then adds the block sums in block
+    order.  Built from the row plan alone."""
+    G = np.asarray(G, np.float32)
+    nc, nw, rpw, win = G.shape[0], spec.num_windows, spec.rows_per_window, \
+        spec.window
+    gidx, vals = row_plan(spec)
+    rp = np.arange(spec.m_pad)
+    with _host_eval():
+        valid = np.asarray(padded_row_valid(spec, rp))
+    gp = np.zeros((nc, spec.m_pad), np.float32)
+    gp[:, :spec.m] = G
+    bpw = -(-rpw // bm)
+    w, j = np.meshgrid(np.arange(nw), np.arange(bpw), indexing="ij")
+    acc = np.zeros((nc, nw, bpw, win), np.float32)
+    for r in range(bm):  # one source row of every (window, block) at once
+        local = j * bm + r
+        row = np.where(local < rpw, w * rpw + local, 0)
+        live = (local < rpw) & valid[row]
+        for k in range(spec.d):
+            term = np.where(live, vals[row, k] * gp[:, row], np.float32(0))
+            c = gidx[row, k] % win
+            acc[:, w, j, c] = acc[:, w, j, c] + term
+    gz = np.zeros((nc, nw, win), np.float32)
+    for jb in range(bpw):
+        gz = gz + acc[:, :, jb]
+    return gz.reshape(nc, -1), np.abs(acc).sum(2).reshape(nc, -1)
+
+
+def _cell_layer0_cotangents(spec, k=10, seed=7):
+    return jnp.asarray(np.random.RandomState(seed).randn(k, spec.m),
+                       jnp.float32)
+
+
+def test_pallas_plan_bwd_chain_cell_layer0():
+    """The sub-binned kernel at the cell's layer-0 widths (K=10) against
+    the float32 chain.  The CPU interpreter's compiler contracts some
+    multiply-adds (the 256-row kernel too), so the gap is bounded at
+    1e-6 of the summed term magnitudes that each chain rounds at."""
+    spec = _cell_layer0()
+    G = _cell_layer0_cotangents(spec)
+    want, mag = _chain_oracle(spec, G)
+    got = np.asarray(qz_reconstruct_batched_bwd_plan(spec, G,
+                                                     interpret=True))
+    assert got.shape == want.shape == (10, spec.n)
+    assert np.all(np.abs(got - want) <= 1e-6 * (np.abs(want) + mag))
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu", reason="TPU only")
+def test_pallas_plan_bwd_chain_exact_on_tpu():
+    """On the chip the chain is a plain multiply then add: the
+    sub-binned kernel gives the float32 chain's bits, and the 256-row
+    one-hot kernel's."""
+    from repro.kernels.qz_reconstruct import _bwd_plan_call
+
+    spec = _cell_layer0()
+    G = _cell_layer0_cotangents(spec)
+    want, _ = _chain_oracle(spec, G)
+    got = np.asarray(qz_reconstruct_batched_bwd_plan(spec, G))
+    whole = np.asarray(_bwd_plan_call(
+        spec, G, _bin_block_plan(spec, 256, 256, "canonical"),
+        interpret=False))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, whole)
 
 
 def test_chunked_plan_matches_unchunked():
